@@ -33,6 +33,8 @@ def suites():
 
 def main(argv=None) -> None:
     args = sys.argv[1:] if argv is None else argv
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     selected = suites()
     if args:
         selected = [s for s in selected

@@ -25,7 +25,9 @@ CONFIG = ModelConfig(
     gated_mlp=False,
     mlp_activation="gelu",
     norm_style="layer",
-    remat=False,
+    remat=True,            # without it the moe DQN update at batch 32
+                           # compiles to ~19 GiB of temporaries for a v5e
+                           # (16 GiB HBM); tests/test_tpu_compile.py pins it
     scan_layers=False,
 )
 SMOKE = CONFIG.replace(n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,

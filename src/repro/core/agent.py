@@ -55,6 +55,22 @@ AGGRESSIVE_METHOD = "transformer+pg"
 
 
 # --------------------------------------------------- offline pretraining
+def make_pretrain_step(fc: FoundationConfig, ocfg: OptimizerConfig):
+    """The pure pretraining step ``(params, opt_state, x, y, time_pos) ->
+    (params, opt_state, loss)``: regress Q(s, submit) on the observed
+    reward."""
+    def loss_fn(p, xb, yb, tb):
+        pred = reward_prediction(p, fc, xb, tb)
+        return jnp.mean(jnp.square(pred - yb))
+
+    def pretrain_step(p, o, xb, yb, tb):
+        loss, g = jax.value_and_grad(loss_fn)(p, xb, yb, tb)
+        p, o, _ = adamw_update(g, p, o, ocfg)
+        return p, o, loss
+
+    return pretrain_step
+
+
 def pretrain_foundation(fc: FoundationConfig, samples: List[Dict],
                         epochs: int = 30, lr: float = 3e-4, seed: int = 0,
                         batch_size: int = 16) -> Tuple[Dict, List[float]]:
@@ -68,16 +84,7 @@ def pretrain_foundation(fc: FoundationConfig, samples: List[Dict],
     X = np.stack([s["matrix"] for s in samples]).astype(np.float32)
     y = np.array([s["reward"] for s in samples], np.float32)
     tp = np.array([s["time_pos"] for s in samples], np.float32)
-
-    def loss_fn(p, xb, yb, tb):
-        pred = reward_prediction(p, fc, xb, tb)
-        return jnp.mean(jnp.square(pred - yb))
-
-    @jax.jit
-    def step(p, o, xb, yb, tb):
-        loss, g = jax.value_and_grad(loss_fn)(p, xb, yb, tb)
-        p, o, _ = adamw_update(g, p, o, ocfg)
-        return p, o, loss
+    step = jax.jit(make_pretrain_step(fc, ocfg))
 
     rng = np.random.default_rng(seed)
     losses = []

@@ -32,6 +32,38 @@ class DQNConfig:
     batch_size: int = 32
 
 
+def learner_opt_config(lr: float) -> OptimizerConfig:
+    """AdamW settings of the online learners (DQN and PG)."""
+    return OptimizerConfig(lr=lr, warmup_steps=10, total_steps=100_000,
+                           weight_decay=0.0, grad_clip=1.0)
+
+
+def make_update(fc: FoundationConfig, dc: DQNConfig):
+    """The pure DQN update ``(params, target_params, opt_state, batch) ->
+    (params, opt_state, loss)`` that ``DQNLearner`` jits."""
+    ocfg = learner_opt_config(dc.lr)
+
+    def loss_fn(params, target_params, batch):
+        q = q_values(params, fc, batch["s"])                     # (B,2)
+        qa = jnp.take_along_axis(q, batch["a"][:, None], 1)[:, 0]
+        if dc.paper_credit:
+            target = batch["r"]
+        else:
+            q_next = q_values(target_params, fc, batch["s2"])
+            target = batch["r"] + dc.gamma * jnp.max(q_next, -1) * (
+                1.0 - batch["done"].astype(jnp.float32))
+        target = jax.lax.stop_gradient(target)
+        return jnp.mean(jnp.square(qa - target))
+
+    def dqn_update(params, target_params, opt_state, batch):
+        loss, grads = jax.value_and_grad(loss_fn)(params, target_params,
+                                                  batch)
+        params, opt_state, _ = adamw_update(grads, params, opt_state, ocfg)
+        return params, opt_state, loss
+
+    return dqn_update
+
+
 class DQNLearner:
     def __init__(self, fc: FoundationConfig, dc: DQNConfig, seed: int = 0,
                  params: Dict = None):
@@ -39,37 +71,12 @@ class DQNLearner:
         key = jax.random.PRNGKey(seed)
         self.params = params if params is not None else init_foundation(key, fc)
         self.target_params = jax.tree.map(jnp.copy, self.params)
-        self.ocfg = OptimizerConfig(lr=dc.lr, warmup_steps=10,
-                                    total_steps=100_000, weight_decay=0.0,
-                                    grad_clip=1.0)
-        self.opt_state = init_opt_state(self.params, self.ocfg)
+        self.opt_state = init_opt_state(self.params,
+                                        learner_opt_config(dc.lr))
         self.rng = np.random.default_rng(seed)
         self._steps = 0
-        self._update = jax.jit(self._make_update())
+        self._update = jax.jit(make_update(fc, dc))
         self._q_fn = jax.jit(lambda p, s: q_values(p, self.fc, s))
-
-    def _make_update(self):
-        fc, dc, ocfg = self.fc, self.dc, self.ocfg
-
-        def loss_fn(params, target_params, batch):
-            q = q_values(params, fc, batch["s"])                 # (B,2)
-            qa = jnp.take_along_axis(q, batch["a"][:, None], 1)[:, 0]
-            if dc.paper_credit:
-                target = batch["r"]
-            else:
-                q_next = q_values(target_params, fc, batch["s2"])
-                target = batch["r"] + dc.gamma * jnp.max(q_next, -1) * (
-                    1.0 - batch["done"].astype(jnp.float32))
-            target = jax.lax.stop_gradient(target)
-            return jnp.mean(jnp.square(qa - target))
-
-        def update(params, target_params, opt_state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, target_params,
-                                                      batch)
-            params, opt_state, _ = adamw_update(grads, params, opt_state, ocfg)
-            return params, opt_state, loss
-
-        return update
 
     # ----------------------------------------------------------- serving
     def act(self, state_matrix: np.ndarray, explore: bool = True) -> int:

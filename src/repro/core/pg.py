@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.train.optimizer import OptimizerConfig, adamw_update, init_opt_state
+from repro.train.optimizer import adamw_update, init_opt_state
+from .dqn import learner_opt_config
 from .foundation import FoundationConfig, init_foundation, policy_logits
 
 
@@ -25,40 +26,42 @@ class PGConfig:
     baseline_momentum: float = 0.9
 
 
+def make_update(fc: FoundationConfig, pc: PGConfig):
+    """The pure REINFORCE update ``(params, opt_state, states, actions,
+    advantage, mask) -> (params, opt_state, loss)`` that ``PGLearner``
+    jits."""
+    ocfg = learner_opt_config(pc.lr)
+
+    def loss_fn(params, states, actions, advantage, mask):
+        logits = policy_logits(params, fc, states)               # (T,2)
+        logp = jax.nn.log_softmax(logits, -1)
+        lp_a = jnp.take_along_axis(logp, actions[:, None], 1)[:, 0]
+        denom = jnp.maximum(mask.sum(), 1.0)
+        entropy = (-jnp.sum(jnp.exp(logp) * logp, -1) * mask).sum() / denom
+        return (-(lp_a * advantage * mask).sum() / denom
+                - pc.entropy_coef * entropy)
+
+    def pg_update(params, opt_state, states, actions, advantage, mask):
+        loss, grads = jax.value_and_grad(loss_fn)(params, states, actions,
+                                                  advantage, mask)
+        params, opt_state, _ = adamw_update(grads, params, opt_state, ocfg)
+        return params, opt_state, loss
+
+    return pg_update
+
+
 class PGLearner:
     def __init__(self, fc: FoundationConfig, pc: PGConfig, seed: int = 0,
                  params: Dict = None):
         self.fc, self.pc = fc, pc
         key = jax.random.PRNGKey(seed)
         self.params = params if params is not None else init_foundation(key, fc)
-        self.ocfg = OptimizerConfig(lr=pc.lr, warmup_steps=10,
-                                    total_steps=100_000, weight_decay=0.0,
-                                    grad_clip=1.0)
-        self.opt_state = init_opt_state(self.params, self.ocfg)
+        self.opt_state = init_opt_state(self.params,
+                                        learner_opt_config(pc.lr))
         self.rng = np.random.default_rng(seed)
         self.baseline = 0.0
-        self._update = jax.jit(self._make_update())
+        self._update = jax.jit(make_update(fc, pc))
         self._logits_fn = jax.jit(lambda p, s: policy_logits(p, self.fc, s))
-
-    def _make_update(self):
-        fc, pc, ocfg = self.fc, self.pc, self.ocfg
-
-        def loss_fn(params, states, actions, advantage, mask):
-            logits = policy_logits(params, fc, states)           # (T,2)
-            logp = jax.nn.log_softmax(logits, -1)
-            lp_a = jnp.take_along_axis(logp, actions[:, None], 1)[:, 0]
-            denom = jnp.maximum(mask.sum(), 1.0)
-            entropy = (-jnp.sum(jnp.exp(logp) * logp, -1) * mask).sum() / denom
-            return (-(lp_a * advantage * mask).sum() / denom
-                    - pc.entropy_coef * entropy)
-
-        def update(params, opt_state, states, actions, advantage, mask):
-            loss, grads = jax.value_and_grad(loss_fn)(params, states, actions,
-                                                      advantage, mask)
-            params, opt_state, _ = adamw_update(grads, params, opt_state, ocfg)
-            return params, opt_state, loss
-
-        return update
 
     # ----------------------------------------------------------- serving
     def act(self, state_matrix: np.ndarray, explore: bool = True) -> int:

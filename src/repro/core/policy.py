@@ -77,7 +77,9 @@ class FallbackPolicy(Policy):
     the predecessor's limit has expired (``pred_remaining <= 0``), the
     same rule as ``baselines.ReactivePolicy`` (inlined to stay import-
     cycle-free). Fallbacks are counted in ``n_fallbacks`` / ``n_decisions``
-    so evaluation results can report how often the learner was bypassed.
+    so evaluation results can report how often the learner was bypassed,
+    and ``last_error`` keeps the type and message of the last exception,
+    so a failing device path is reported rather than silently answered.
     """
 
     def __init__(self, inner: Policy, deadline_s: Optional[float] = None,
@@ -88,6 +90,8 @@ class FallbackPolicy(Policy):
         self.clock = clock
         self.n_decisions = 0
         self.n_fallbacks = 0
+        # "<type>: <message>" of the last exception the inner policy raised
+        self.last_error: Optional[str] = None
 
     @staticmethod
     def _reactive(obs: Dict) -> np.ndarray:
@@ -98,8 +102,9 @@ class FallbackPolicy(Policy):
         t0 = self.clock()
         try:
             acts = np.asarray(self.inner.act_batch(obs), np.int64)
-        except Exception:
+        except Exception as e:
             self.n_fallbacks += 1
+            self.last_error = f"{type(e).__name__}: {e}"
             return self._reactive(obs)
         if self.deadline_s is not None and self.clock() - t0 > self.deadline_s:
             self.n_fallbacks += 1

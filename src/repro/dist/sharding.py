@@ -33,13 +33,9 @@ def axis_size(mesh, name: str) -> int:
 
 
 def make_abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """Device-free mesh for spec-only work, across jax API generations
-    (older AbstractMesh takes a shape_tuple; newer takes sizes + names)."""
+    """Device-free mesh for spec-only work."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def batch_axes(mesh, global_batch: int) -> Tuple[str, ...]:

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pltpu_compat import CompilerParams as _CompilerParams
-
 
 def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref):
     di = pl.program_id(3)
@@ -64,7 +62,7 @@ def grouped_gemm(x, w, *, block_c: int = 128, block_f: int = 128,
                                lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, fp), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

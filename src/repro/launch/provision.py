@@ -2,11 +2,14 @@
 
   PYTHONPATH=src python -m repro.launch.provision \
       --cluster V100 --method moe+dqn --load 1.0 --episodes 10 \
-      [--save-agent checkpoints/agent]
+      [--save-agent checkpoints/agent] [--smoke]
 
 Runs the paper's full §4.9 procedure on a freshly synthesized (seeded)
 trace: offline sample collection -> foundation pretraining -> online RL ->
-validation-split evaluation against the reactive baseline.
+validation-split evaluation against the reactive baseline. The agent runs
+at its configured width (configs/mirage_agent.py) over the registry's
+144-snapshot history at 600 s intervals; ``--smoke`` swaps in the reduced
+trunk at history 24 and 1800 s intervals, small enough for a CPU.
 
 Robustness flags: ``--fault faulty`` threads the named fault profile's
 deterministic FaultPlan (node failures + transient control errors)
@@ -35,8 +38,8 @@ def main():
     ap.add_argument("--online-episodes", type=int, default=8)
     ap.add_argument("--offline-episodes", type=int, default=4)
     ap.add_argument("--pretrain-epochs", type=int, default=6)
-    ap.add_argument("--history", type=int, default=24)
-    ap.add_argument("--interval", type=float, default=1800.0)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced agent at history 24 / 1800 s (CPU-sized)")
     ap.add_argument("--nodes", type=int, default=1, help="chain job size")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--save-agent", default=None)
@@ -53,7 +56,10 @@ def main():
                          "crash-consistent recovery); uses --chain-links "
                          "links per tenant (default 2)")
     args = ap.parse_args()
+    history, interval = (24, 1800.0) if args.smoke else (144, 600.0)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.core import (ChainDriver, DecisionJournal, EnvConfig,
                             ProvisionEnv, ReplayCheckpointCache,
                             build_policy, evaluate_batch)
@@ -74,8 +80,8 @@ def main():
         print(f"[provision] fault profile {args.fault}: "
               f"{len(faults) // 2} failure windows, "
               f"ctrl error rate {faults.ctrl_error_rate}")
-    ecfg = EnvConfig(n_nodes=profile.n_nodes, history=args.history,
-                     interval=args.interval, chain_nodes=args.nodes,
+    ecfg = EnvConfig(n_nodes=profile.n_nodes, history=history,
+                     interval=interval, chain_nodes=args.nodes,
                      faults=faults)
     cache = ReplayCheckpointCache(jobs, profile.n_nodes, faults=faults)
     env_train = ProvisionEnv(jobs, ecfg, seed=args.seed, cache=cache)
@@ -91,7 +97,8 @@ def main():
     policy = build_policy(args.method, env_train, offline_samples=samples,
                           online_episodes=args.online_episodes,
                           pretrain_epochs=args.pretrain_epochs,
-                          history=args.history, reduced=True, seed=args.seed)
+                          history=history, reduced=args.smoke,
+                          seed=args.seed)
     print(f"[provision] trained {args.method} ({time.time()-t0:.0f}s)")
 
     venv = make_vector_env(jobs, ecfg, args.episodes, seed=args.seed,
@@ -127,6 +134,9 @@ def main():
                   f"{t.interruption_h:.2f}h, overlap {t.overlap_h:.2f}h, "
                   f"{t.n_decisions} decisions ({t.n_fallbacks} fallbacks), "
                   f"ctrl errors {t.n_ctrl_errors}")
+        if service.policy.last_error:
+            print(f"[provision]   last fallback error: "
+                  f"{service.policy.last_error}")
     elif args.chain_links > 0:
         journal = DecisionJournal(args.journal) if args.journal else None
         driver = ChainDriver(jobs, ecfg, policy, links=args.chain_links,
@@ -139,6 +149,9 @@ def main():
               f"{cres.n_fallbacks} fallbacks), ctrl errors "
               f"{cres.n_ctrl_errors} ({cres.n_retries} retries), "
               f"faults {cres.n_faults}, requeues {cres.n_requeues}")
+        if driver.policy.last_error:
+            print(f"[provision]   last fallback error: "
+                  f"{driver.policy.last_error}")
 
     if args.save_agent and policy.learner is not None:
         from repro.train.checkpoint import save_checkpoint

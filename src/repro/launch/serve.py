@@ -26,6 +26,8 @@ def main():
     ap.add_argument("--wall-limit", type=float, default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     import jax
     import numpy as np
     from repro.models import registry, transformer

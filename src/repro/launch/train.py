@@ -35,6 +35,8 @@ def main():
                     help="call jax.distributed.initialize() (multi-host pods)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.distributed:
         import jax
         jax.distributed.initialize()

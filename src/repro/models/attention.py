@@ -227,9 +227,15 @@ def attention_flash(q, k, v, q_pos, kv_pos, *, causal, window=0, softcap=0.0,
                     scale=None, kv_len_valid=None, interpret=None):
     from repro.kernels.flash_attention import ops as fa_ops
     if interpret is None:
-        # Pallas TPU kernels execute natively on TPU; everywhere else
-        # (CPU tests, this container) they run in interpret mode.
-        interpret = jax.default_backend() != "tpu"
+        # Pallas TPU kernels execute natively on TPU and in interpret mode
+        # on the CPU (tests); any other backend is refused rather than
+        # silently interpreted.
+        backend = jax.default_backend()
+        if backend not in ("tpu", "cpu"):
+            raise NotImplementedError(
+                f"flash attention runs on tpu (or interpreted on cpu), "
+                f"not on {backend!r}")
+        interpret = backend == "cpu"
     return fa_ops.flash_attention(
         q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
         interpret=interpret)

@@ -296,6 +296,21 @@ def test_fallback_policy_on_exception_and_deadline():
     assert pol3.n_fallbacks == 0 and pol3.n_decisions == 1
 
 
+def test_fallback_policy_keeps_last_error():
+    class Exploding(ReactivePolicy):
+        def act_batch(self, obs):
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    obs = {"pred_remaining": np.array([0.0])}
+    pol = FallbackPolicy(Exploding())
+    assert pol.last_error is None
+    pol.act_batch(obs)
+    assert pol.last_error == "RuntimeError: RESOURCE_EXHAUSTED: out of HBM"
+    healthy = FallbackPolicy(ReactivePolicy())
+    healthy.act_batch(obs)
+    assert healthy.n_fallbacks == 0 and healthy.last_error is None
+
+
 # -------------------------------------------------------- chain driver
 def test_chain_driver_completes_with_retries(faulty_chain_world):
     jobs, cfg, cache = faulty_chain_world

@@ -39,7 +39,7 @@ def test_serve_launcher_smoke():
 
 
 def test_provision_service_launcher_smoke(tmp_path):
-    args = ["repro.launch.provision", "--method", "reactive",
+    args = ["repro.launch.provision", "--smoke", "--method", "reactive",
             "--episodes", "2", "--fault", "faulty", "--service", "3",
             "--chain-links", "1", "--journal", str(tmp_path / "journals")]
     r = run_mod(args)
