@@ -20,7 +20,6 @@ TPU. Times printed before the last line are smoke timings of one run, not
 benchmark metrics. The last line of stdout is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
 """
-import collections
 import contextlib
 import json
 import os
@@ -40,31 +39,6 @@ Q_TOL = 1e-2          # |q_tpu - q_cpu| <= Q_TOL * max(1, |q_cpu|): a few
                       # bf16 rounding units (2^-8) of the largest Q-value
 
 
-class CompileLog:
-    """Backend compiles (with their seconds) and persistent-cache hits,
-    per jitted program, from JAX's monitoring events."""
-
-    def __init__(self):
-        import jax
-        self.programs = collections.defaultdict(lambda: [0, 0.0])
-        self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            entry = self.programs[kw.get("fun_name", "?")]
-            entry[0] += 1
-            entry[1] += secs
-
-    def _event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def total_s(self) -> float:
-        return sum(s for _, s in self.programs.values())
-
-
 def _say(msg: str) -> None:
     print(f"[chip_smoke] {msg}", flush=True)
 
@@ -75,14 +49,14 @@ def _check(ok, what: str) -> None:
 
 
 @contextlib.contextmanager
-def _phase(name: str, log: CompileLog):
+def _phase(name: str, log: "CompileLog"):
     c0, t0 = log.total_s(), time.perf_counter()
     yield
     _say(f"{name}: {time.perf_counter() - t0:.1f}s wall "
          f"({log.total_s() - c0:.1f}s compiling)  [smoke timing]")
 
 
-def smoke(log: CompileLog) -> None:
+def smoke(log: "CompileLog") -> None:
     """Run the seven phases; raises RuntimeError on a failed check."""
     import jax
     import numpy as np
@@ -185,6 +159,7 @@ def main() -> int:
         return 1
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro.launch.compile_cache import use_compile_cache
+    from repro.telemetry import CompileLog
     _say(f"device {dev.device_kind} x {len(devices)}; compile cache "
          f"{use_compile_cache()}")
     log = CompileLog()

@@ -54,6 +54,7 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 import msgpack
 import numpy as np
 
+from repro import telemetry
 from repro.sim.faults import FaultPlan
 from repro.sim.trace import Job
 from repro.sim.workload import pair_outcome
@@ -209,12 +210,13 @@ class DecisionJournal:
         self.path = path
 
     def append(self, record: Dict) -> None:
-        body = msgpack.packb(record, use_bin_type=True)
-        frame = _FRAME.pack(len(body), zlib.crc32(body)) + body
-        with open(self.path, "ab") as f:
-            f.write(frame)
-            f.flush()
-            os.fsync(f.fileno())
+        with telemetry.span("journal.append"):
+            body = msgpack.packb(record, use_bin_type=True)
+            frame = _FRAME.pack(len(body), zlib.crc32(body)) + body
+            with open(self.path, "ab") as f:
+                f.write(frame)
+                f.flush()
+                os.fsync(f.fileno())
 
     def replay(self) -> List[Dict]:
         """All complete records on disk, in append order. A torn tail is
@@ -498,10 +500,11 @@ class ChainLane:
     def apply(self, action: int, fell_back: bool = False) -> None:
         """Journal one live decision, then apply it to the simulator."""
         assert not self.done
-        if self.journal:
-            self.journal.append({"i": self._di, "a": int(action),
-                                 "fb": bool(fell_back)})
-        self._apply(int(action), bool(fell_back))
+        with telemetry.span("lane.apply"):
+            if self.journal:
+                self.journal.append({"i": self._di, "a": int(action),
+                                     "fb": bool(fell_back)})
+            self._apply(int(action), bool(fell_back))
 
     def _apply(self, action: int, fell_back: bool) -> None:
         env = self.env

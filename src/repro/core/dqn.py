@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.train.optimizer import OptimizerConfig, adamw_update, init_opt_state
 from .foundation import FoundationConfig, init_foundation, q_values
 
@@ -56,9 +57,11 @@ def make_update(fc: FoundationConfig, dc: DQNConfig):
         return jnp.mean(jnp.square(qa - target))
 
     def dqn_update(params, target_params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, target_params,
-                                                  batch)
-        params, opt_state, _ = adamw_update(grads, params, opt_state, ocfg)
+        with jax.named_scope("dqn_update"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, target_params,
+                                                      batch)
+            params, opt_state, _ = adamw_update(grads, params, opt_state,
+                                                ocfg)
         return params, opt_state, loss
 
     return dqn_update
@@ -88,13 +91,19 @@ class DQNLearner:
     def act_batch(self, state_matrices: np.ndarray,
                   explore: bool = True) -> np.ndarray:
         """Vectorized policy over a (B, k, 40) stack -> (B,) actions.
-        One jitted forward serves the whole batch (the vector-env path)."""
-        q = np.asarray(self._q_fn(self.params, jnp.asarray(state_matrices)))
-        a = np.argmax(q, axis=-1)
-        if explore:
-            b = len(a)
-            flip = self.rng.random(b) < self.dc.epsilon
-            a = np.where(flip, self.rng.integers(0, 2, b), a)
+        One jitted forward serves the whole batch (the vector-env path).
+        The host waits once, for the outputs' copy: waiting for the
+        forward and then copying would cost it a second wake-up."""
+        with telemetry.span("forward.launch"):
+            q = self._q_fn(self.params, jnp.asarray(state_matrices))
+        with telemetry.span("forward.wait"):
+            q = np.asarray(q)
+        with telemetry.span("forward.fetch"):
+            a = np.argmax(q, axis=-1)
+            if explore:
+                b = len(a)
+                flip = self.rng.random(b) < self.dc.epsilon
+                a = np.where(flip, self.rng.integers(0, 2, b), a)
         return a.astype(np.int64)
 
     # ----------------------------------------------------------- learning

@@ -107,22 +107,26 @@ def _gate(params: Dict, fc: FoundationConfig, states: jnp.ndarray,
 
 def q_values(params: Dict, fc: FoundationConfig, states: jnp.ndarray,
              time_pos: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Q(s, a) for both actions. Returns (B, 2): [:,0]=no-submit, [:,1]=submit."""
+    """Q(s, a) for both actions. Returns (B, 2): [:,0]=no-submit, [:,1]=submit.
+    The two trunk passes carry the names ``action_wait`` and
+    ``action_submit`` in the device's trace."""
     B = states.shape[0]
 
     def both(trunk_params):
         qs = []
-        for a in (-1.0, 1.0):
-            feats = _trunk_apply(trunk_params, fc,
-                                 states, jnp.full((B,), a))
-            qs.append(_heads(trunk_params, feats)[0])
+        for name, a in (("action_wait", -1.0), ("action_submit", 1.0)):
+            with jax.named_scope(name):
+                feats = _trunk_apply(trunk_params, fc,
+                                     states, jnp.full((B,), a))
+                qs.append(_heads(trunk_params, feats)[0])
         return jnp.stack(qs, axis=-1)                      # (B, 2)
 
-    if fc.kind == "transformer":
-        return both(params)
-    per_exp = jax.vmap(both, in_axes=(0,))(params["experts"])   # (E, B, 2)
-    g = _gate(params, fc, states, time_pos)                      # (B, E)
-    return jnp.einsum("ebq,be->bq", per_exp, g)
+    with jax.named_scope("q_values"):
+        if fc.kind == "transformer":
+            return both(params)
+        per_exp = jax.vmap(both, in_axes=(0,))(params["experts"])  # (E,B,2)
+        g = _gate(params, fc, states, time_pos)                     # (B, E)
+        return jnp.einsum("ebq,be->bq", per_exp, g)
 
 
 def policy_logits(params: Dict, fc: FoundationConfig, states: jnp.ndarray,
@@ -134,11 +138,12 @@ def policy_logits(params: Dict, fc: FoundationConfig, states: jnp.ndarray,
         feats = _trunk_apply(trunk_params, fc, states, jnp.zeros((B,)))
         return _heads(trunk_params, feats)[1]
 
-    if fc.kind == "transformer":
-        return one(params)
-    per_exp = jax.vmap(one, in_axes=(0,))(params["experts"])    # (E, B, 2)
-    g = _gate(params, fc, states, time_pos)
-    return jnp.einsum("ebq,be->bq", per_exp, g)
+    with jax.named_scope("policy_logits"):
+        if fc.kind == "transformer":
+            return one(params)
+        per_exp = jax.vmap(one, in_axes=(0,))(params["experts"])  # (E,B,2)
+        g = _gate(params, fc, states, time_pos)
+        return jnp.einsum("ebq,be->bq", per_exp, g)
 
 
 def reward_prediction(params: Dict, fc: FoundationConfig, states: jnp.ndarray,

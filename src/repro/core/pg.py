@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.train.optimizer import adamw_update, init_opt_state
 from .dqn import learner_opt_config
 from .foundation import FoundationConfig, init_foundation, policy_logits
@@ -42,9 +43,11 @@ def make_update(fc: FoundationConfig, pc: PGConfig):
                 - pc.entropy_coef * entropy)
 
     def pg_update(params, opt_state, states, actions, advantage, mask):
-        loss, grads = jax.value_and_grad(loss_fn)(params, states, actions,
-                                                  advantage, mask)
-        params, opt_state, _ = adamw_update(grads, params, opt_state, ocfg)
+        with jax.named_scope("pg_update"):
+            loss, grads = jax.value_and_grad(loss_fn)(
+                params, states, actions, advantage, mask)
+            params, opt_state, _ = adamw_update(grads, params, opt_state,
+                                                ocfg)
         return params, opt_state, loss
 
     return pg_update
@@ -71,13 +74,19 @@ class PGLearner:
 
     def act_batch(self, state_matrices: np.ndarray,
                   explore: bool = True) -> np.ndarray:
-        """Vectorized sampling over a (B, k, 40) stack -> (B,) actions."""
-        logits = self._logits_fn(self.params, jnp.asarray(state_matrices))
-        p = np.asarray(jax.nn.softmax(logits, -1))
-        if explore:
-            u = self.rng.random(len(p))
-            return (u < p[:, 1]).astype(np.int64)
-        return np.argmax(p, axis=-1).astype(np.int64)
+        """Vectorized sampling over a (B, k, 40) stack -> (B,) actions.
+        The host waits once, for the probabilities' copy."""
+        with telemetry.span("forward.launch"):
+            logits = self._logits_fn(self.params,
+                                     jnp.asarray(state_matrices))
+            p = jax.nn.softmax(logits, -1)
+        with telemetry.span("forward.wait"):
+            p = np.asarray(p)
+        with telemetry.span("forward.fetch"):
+            if explore:
+                u = self.rng.random(len(p))
+                return (u < p[:, 1]).astype(np.int64)
+            return np.argmax(p, axis=-1).astype(np.int64)
 
     # ----------------------------------------------------------- learning
     def train_on_episode(self, states: np.ndarray, actions: np.ndarray,

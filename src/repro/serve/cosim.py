@@ -41,6 +41,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro import telemetry
 from repro.core.control import (JOURNAL_VERSION, ChainLane, ChainResult,
                                 DecisionJournal, JournalCorruptionError,
                                 RetryPolicy)
@@ -130,11 +131,12 @@ class CoSimChainLane(ChainLane):
         """Journal one live decision (tagged with the shared round), then
         apply it — deferred into the world's round protocol."""
         assert self.awaiting
-        if self.journal:
-            self.journal.append({"i": self._di, "a": int(action),
-                                 "fb": bool(fell_back),
-                                 "r": self.cosim.round})
-        self._apply(int(action), bool(fell_back))
+        with telemetry.span("lane.apply"):
+            if self.journal:
+                self.journal.append({"i": self._di, "a": int(action),
+                                     "fb": bool(fell_back),
+                                     "r": self.cosim.round})
+            self._apply(int(action), bool(fell_back))
 
     def _apply(self, action: int, fell_back: bool) -> None:
         self._di += 1
@@ -231,31 +233,34 @@ class CoSimWorld:
                 lane.journal.append(lane._header())
         self.round = 0
         cfg = self.cfg
-        wp = max(self.t0 - cfg.history * cfg.interval, 0.0)
-        sim = self.cache.fork_at(wp)
-        self.world = MultiTenantSim(sim, self.tenants)
-        for lane in self.lanes:
-            lane._reset_state()
-            lane.env.sim = sim
+        with telemetry.span("cosim.fork"):
+            wp = max(self.t0 - cfg.history * cfg.interval, 0.0)
+            sim = self.cache.fork_at(wp)
+            self.world = MultiTenantSim(sim, self.tenants)
+            for lane in self.lanes:
+                lane._reset_state()
+                lane.env.sim = sim
         # warm up: the scalar push sequence (snapshot at the window head,
         # one per interval crossing) — tenants share every snapshot until
         # their predecessors differentiate the lanes
-        self._push_shared()
-        while sim.now + cfg.interval <= self.t0:
-            sim.step(cfg.interval)
+        with telemetry.span("cosim.warmup"):
             self._push_shared()
-        if sim.now < self.t0:
-            sim.step(self.t0 - sim.now)
+            while sim.now + cfg.interval <= self.t0:
+                sim.step(cfg.interval)
+                self._push_shared()
+            if sim.now < self.t0:
+                sim.step(self.t0 - sim.now)
         # inject + start the predecessors, in tenant order
-        for lane in self.lanes:
-            chain = make_tenant_chain(lane.tenant, lane.env.rng,
-                                      cfg.chain_nodes, cfg.sub_limit)
-            lane.env.chain = chain
-            lane.env.pred = self.world.submit_pred(lane.tenant, chain)
-        self.world.start_preds()
-        for lane in self.lanes:
-            lane.env.hist.push(lane.env._snapshot())
-            lane.obs = lane.env.obs()
+        with telemetry.span("cosim.inject"):
+            for lane in self.lanes:
+                chain = make_tenant_chain(lane.tenant, lane.env.rng,
+                                          cfg.chain_nodes, cfg.sub_limit)
+                lane.env.chain = chain
+                lane.env.pred = self.world.submit_pred(lane.tenant, chain)
+            self.world.start_preds()
+            for lane in self.lanes:
+                lane.env.hist.push(lane.env._snapshot())
+                lane.obs = lane.env.obs()
         self._rehydrate(bodies)
 
     def _push_shared(self) -> None:
@@ -313,22 +318,25 @@ class CoSimWorld:
         clock one interval — or fast-forward every pending successor to
         its start when no lane is waiting — resolve the started
         successors, and refresh the waiting lanes' windows."""
-        w = self.world
-        sim = w.sim
-        round_t0 = sim.now
-        w.flush_submits(submit=self._ctrl_submit)
-        waiting = w.waiting.copy()
-        if waiting.any():
-            w.run_until(round_t0 + self.cfg.interval)
-        else:
-            w.fast_forward()
-        for out in w.resolve_ready():
-            self.lanes[out.tenant]._finish_link(out)
-        self.round += 1
-        for t in np.flatnonzero(waiting):
-            lane = self.lanes[int(t)]
-            if not lane.done:
-                lane.env.hist.push(lane.env._snapshot())
-        for lane in self.lanes:
-            if not lane.done and not w.pending[lane.tenant]:
-                lane.obs = lane.env.obs()
+        with telemetry.span("cosim.advance"):
+            w = self.world
+            with telemetry.span("sim.advance"):
+                round_t0 = w.sim.now
+                w.flush_submits(submit=self._ctrl_submit)
+                waiting = w.waiting.copy()
+                if waiting.any():
+                    w.run_until(round_t0 + self.cfg.interval)
+                else:
+                    w.fast_forward()
+                started = w.resolve_ready()
+            for out in started:
+                self.lanes[out.tenant]._finish_link(out)
+            self.round += 1
+            with telemetry.span("state.encode"):
+                for t in np.flatnonzero(waiting):
+                    lane = self.lanes[int(t)]
+                    if not lane.done:
+                        lane.env.hist.push(lane.env._snapshot())
+                for lane in self.lanes:
+                    if not lane.done and not w.pending[lane.tenant]:
+                        lane.obs = lane.env.obs()

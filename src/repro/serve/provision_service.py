@@ -30,10 +30,11 @@ point:
   uninterrupted run — no lost, no double-applied decisions.
 
 ``health()`` serves a readiness snapshot (queue depth, breaker state,
-per-tenant lag) at any point. The ``serve_decisions`` tracked benchmark
-(``benchmarks/bench_serve.py``) gates decisions/sec, p99 decision
-latency and degraded-mode throughput via ``scripts/check_bench.py
-serve``.
+per-tenant lag) at any point; ``repro.telemetry`` holds the service's
+host spans (build, start, round, batch, apply, journal append), recorded
+while a profile is taken. ``serve_decisions`` (``benchmarks/bench_serve.py``,
+gated by ``scripts/check_bench.py serve``) is a CPU canary, not a
+measurement of the service on its device.
 """
 from __future__ import annotations
 
@@ -44,6 +45,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import telemetry
 from repro.core.control import (ChainLane, ChainResult, CircuitBreaker,
                                 DecisionJournal, RetryPolicy)
 from repro.core.policy import FallbackPolicy, Policy, stack_obs
@@ -137,34 +139,35 @@ class ProvisionService:
         self.svc = svc or ServiceConfig()
         self.seed = seed
         self.clock = clock
-        self.cache = cache if cache is not None else ReplayCheckpointCache(
-            trace, cfg.n_nodes, faults=cfg.faults)
-        if journal_dir:
-            os.makedirs(journal_dir, exist_ok=True)
+        with telemetry.span("service.build"):
+            self.cache = cache if cache is not None else ReplayCheckpointCache(
+                trace, cfg.n_nodes, faults=cfg.faults)
+            if journal_dir:
+                os.makedirs(journal_dir, exist_ok=True)
 
-        def _journal(i: int) -> Optional[DecisionJournal]:
-            return (DecisionJournal(os.path.join(
-                journal_dir, f"tenant_{i:05d}.journal"))
-                if journal_dir else None)
+            def _journal(i: int) -> Optional[DecisionJournal]:
+                return (DecisionJournal(os.path.join(
+                    journal_dir, f"tenant_{i:05d}.journal"))
+                    if journal_dir else None)
 
-        if self.svc.co_sim:
-            self.cosim: Optional[CoSimWorld] = CoSimWorld(
-                trace, cfg, self.svc.tenants, seed=seed, cache=self.cache)
-            self.lanes: List[ChainLane] = [
-                CoSimChainLane(trace, cfg, self.cosim, i,
-                               links=self.svc.links, seed=seed + i,
-                               journal=_journal(i),
-                               retry=retry_factory(i) if retry_factory
-                               else None, cache=self.cache)
-                for i in range(self.svc.tenants)]
-        else:
-            self.cosim = None
-            self.lanes = [
-                ChainLane(trace, cfg, links=self.svc.links, seed=seed + i,
-                          journal=_journal(i),
-                          retry=retry_factory(i) if retry_factory else None,
-                          cache=self.cache)
-                for i in range(self.svc.tenants)]
+            if self.svc.co_sim:
+                self.cosim: Optional[CoSimWorld] = CoSimWorld(
+                    trace, cfg, self.svc.tenants, seed=seed, cache=self.cache)
+                self.lanes: List[ChainLane] = [
+                    CoSimChainLane(trace, cfg, self.cosim, i,
+                                   links=self.svc.links, seed=seed + i,
+                                   journal=_journal(i),
+                                   retry=retry_factory(i) if retry_factory
+                                   else None, cache=self.cache)
+                    for i in range(self.svc.tenants)]
+            else:
+                self.cosim = None
+                self.lanes = [
+                    ChainLane(trace, cfg, links=self.svc.links, seed=seed + i,
+                              journal=_journal(i),
+                              retry=retry_factory(i) if retry_factory else None,
+                              cache=self.cache)
+                    for i in range(self.svc.tenants)]
         self.policy = (policy if isinstance(policy, FallbackPolicy)
                        else FallbackPolicy(
                            policy, deadline_s=self.svc.decision_deadline_s,
@@ -195,14 +198,15 @@ class ProvisionService:
         co-sim mode the tenants share one episode start — ``t_starts[0]``
         pins it (the rest are ignored); the journals replay together, in
         shared-round order."""
-        if self.cosim is not None:
-            t0 = (float(np.asarray(t_starts, np.float64).ravel()[0])
-                  if t_starts is not None else None)
-            self.cosim.begin(t_start=t0)
-        else:
-            for i, lane in enumerate(self.lanes):
-                lane.begin(t_start=t_starts[i] if t_starts is not None
-                           else None)
+        with telemetry.span("service.start"):
+            if self.cosim is not None:
+                t0 = (float(np.asarray(t_starts, np.float64).ravel()[0])
+                      if t_starts is not None else None)
+                self.cosim.begin(t_start=t0)
+            else:
+                for i, lane in enumerate(self.lanes):
+                    lane.begin(t_start=t_starts[i] if t_starts is not None
+                               else None)
         self.started = True
 
     # --------------------------------------------------------- admission
@@ -248,38 +252,41 @@ class ProvisionService:
     def _serve_chunk(self, chunk: List[int]) -> None:
         """One dynamic batch: stack the chunk's observations, answer via
         the breaker-gated policy, journal-then-apply each decision."""
-        obs = stack_obs([self.lanes[i].obs for i in chunk])
-        t0 = self.clock()
-        if not self.breaker.allow():
-            acts = self._reactive(obs)
-            fell_back = True
-            self.n_degraded += len(chunk)
-        else:
-            fb0 = self.policy.n_fallbacks
-            acts = np.asarray(self.policy.act_batch(obs), np.int64)
-            fell_back = self.policy.n_fallbacks > fb0
-            self.breaker.record(not fell_back)
-        dt = self.clock() - t0
-        self._est_batch_s = (dt if self.n_batches == 0
-                             else 0.8 * self._est_batch_s + 0.2 * dt)
-        self.n_batches += 1
-        for i, a in zip(chunk, acts):
-            lane = self.lanes[i]
-            lane.apply(int(a), fell_back=fell_back)
-            self.n_decisions += 1
-            self._last_round[i] = self.n_rounds
-            self._latencies.append(self.clock() - self._arrival[i])
+        with telemetry.span("service.batch"):
+            with telemetry.span("policy.stack"):
+                obs = stack_obs([self.lanes[i].obs for i in chunk])
+            t0 = self.clock()
+            if not self.breaker.allow():
+                acts = self._reactive(obs)
+                fell_back = True
+                self.n_degraded += len(chunk)
+            else:
+                fb0 = self.policy.n_fallbacks
+                acts = np.asarray(self.policy.act_batch(obs), np.int64)
+                fell_back = self.policy.n_fallbacks > fb0
+                self.breaker.record(not fell_back)
+            dt = self.clock() - t0
+            self._est_batch_s = (dt if self.n_batches == 0
+                                 else 0.8 * self._est_batch_s + 0.2 * dt)
+            self.n_batches += 1
+            for i, a in zip(chunk, acts):
+                lane = self.lanes[i]
+                lane.apply(int(a), fell_back=fell_back)
+                self.n_decisions += 1
+                self._last_round[i] = self.n_rounds
+                self._latencies.append(self.clock() - self._arrival[i])
 
     def _round(self, live: List[int]) -> None:
         """One service round: admit, then serve the queue in batches.
         A drain request (``guard``) finishes the in-flight batch —
         journaling included — and abandons the rest of the round."""
         self.n_rounds += 1
-        admitted = self._admit(live)
-        for c0 in range(0, len(admitted), self.svc.max_batch):
-            if c0 > 0 and self.guard.should_stop():
-                break                            # graceful drain mid-round
-            self._serve_chunk(admitted[c0:c0 + self.svc.max_batch])
+        with telemetry.span("service.round", request=self.n_rounds):
+            admitted = self._admit(live)
+            for c0 in range(0, len(admitted), self.svc.max_batch):
+                if c0 > 0 and self.guard.should_stop():
+                    break                        # graceful drain mid-round
+                self._serve_chunk(admitted[c0:c0 + self.svc.max_batch])
 
     # ---------------------------------------------------------------- run
     def live_tenants(self) -> List[int]:
@@ -326,21 +333,24 @@ class ProvisionService:
                 reason = "max_rounds"
                 break
             self.n_rounds += 1
-            awaiting = [i for i in live if self.lanes[i].awaiting]
-            if awaiting:
-                now = self.clock()
-                for i in awaiting:
-                    self._arrival[i] = now
-                interrupted = False
-                for c0 in range(0, len(awaiting), self.svc.max_batch):
-                    if c0 > 0 and self.guard.should_stop():
-                        interrupted = True   # graceful drain mid-round
-                        break
-                    self._serve_chunk(awaiting[c0:c0 + self.svc.max_batch])
-                if interrupted:
-                    continue                 # round stays un-advanced
-            self.cosim.advance_round()
+            with telemetry.span("service.round", request=self.n_rounds):
+                self._co_round(live)
         return self._result(reason)
+
+    def _co_round(self, live: List[int]) -> None:
+        """Serve one shared round's awaiting tenants in batches, then
+        advance the round. A drain request finishes the in-flight batch
+        and leaves the round un-advanced."""
+        awaiting = [i for i in live if self.lanes[i].awaiting]
+        if awaiting:
+            now = self.clock()
+            for i in awaiting:
+                self._arrival[i] = now
+            for c0 in range(0, len(awaiting), self.svc.max_batch):
+                if c0 > 0 and self.guard.should_stop():
+                    return                   # graceful drain mid-round
+                self._serve_chunk(awaiting[c0:c0 + self.svc.max_batch])
+        self.cosim.advance_round()
 
     def _result(self, reason: str) -> ServiceResult:
         tenants = [lane.result("completed" if lane.done else reason)

@@ -3,6 +3,8 @@ batching equivalence, kill-at-arbitrary-point recovery, circuit-breaker
 degradation, deadline-aware load shedding and graceful drain. All chaos
 is seeded and clocks/sleeps are injected — no wall-clock waits.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -384,3 +386,57 @@ def test_cosim_faults_attributed_to_owning_tenant(world, co_reference):
     assert w.sim.n_node_failures > 0
     assert sum(t.n_faults for t in res.tenants) <= w.sim.n_node_failures
     assert sum(t.n_requeues for t in res.tenants) <= w.sim.n_requeues
+
+
+# --------------------------------------------------------- program spans
+def test_cosim_spans_count_the_service_and_change_nothing(world, tmp_path):
+    """Recording the program's spans counts one ``service.round`` per
+    round, one ``forward.wait`` per batch and one ``lane.apply`` per
+    decision, and changes nothing served: the actions and the per-tenant
+    schedules match a run with recording off bit for bit."""
+    import dataclasses
+
+    from repro import telemetry
+    from repro.core import (DQNConfig, DQNLearner, FoundationConfig,
+                            LearnerPolicy)
+    fc = dataclasses.replace(FoundationConfig(kind="transformer").reduced(),
+                             history=world[1].history)
+    learner = DQNLearner(fc, DQNConfig(), seed=0)
+
+    class Logged(LearnerPolicy):
+        def __init__(self):
+            super().__init__("transformer+dqn", learner)
+            self.actions = []
+
+        def act_batch(self, obs):
+            acts = super().act_batch(obs)
+            self.actions.append(acts.tolist())
+            return acts
+
+    def serve(name):
+        policy = Logged()
+        svc = _co_service(world, policy=policy,
+                          journal_dir=str(tmp_path / name))
+        return svc.run(), policy.actions
+
+    telemetry.reset()
+    off, off_actions = serve("off")
+    with telemetry.capture():
+        t0 = time.perf_counter()
+        on, on_actions = serve("on")
+        t1 = time.perf_counter()
+    assert telemetry.totals(0.0, t0) == {}        # nothing while off
+    tot = telemetry.totals(t0, t1)
+    assert on.reason == "completed" and on.n_batches > on.n_rounds > 0
+    assert tot["service.round"].count == on.n_rounds
+    assert tot["forward.wait"].count == tot["forward.launch"].count \
+        == tot["forward.fetch"].count == on.n_batches
+    assert tot["lane.apply"].count == on.n_decisions
+    # one header per tenant, then one record per decision
+    assert tot["journal.append"].count == on.n_decisions + TENANTS
+    assert tot["service.start"].count == tot["service.build"].count == 1
+    assert tot["cosim.advance"].count == tot["sim.advance"].count \
+        == tot["state.encode"].count
+    assert on_actions == off_actions and on_actions
+    assert _schedules(on) == _schedules(off)
+    telemetry.reset()
