@@ -97,8 +97,9 @@ class ProvisionEnv:
                 trace[0].submit_time + cfg.warmup + DAY))
 
     # ------------------------------------------------------------ helpers
-    def _snapshot(self) -> np.ndarray:
-        s = self.sim.sample()
+    def pair_info(self) -> Tuple[Optional[Dict], Dict]:
+        """The (predecessor, successor) infos of the snapshot's pair
+        columns at the simulator's current instant."""
         pred_info = None
         if self.pred is not None:
             pred_info = {
@@ -108,8 +109,11 @@ class ProvisionEnv:
                             if self.pred.start_time >= 0 else 0.0),
             }
         succ_info = {"size": self.cfg.chain_nodes, "limit": self.cfg.sub_limit}
-        return encode_snapshot(s, self.cfg.n_nodes, self.cfg.sub_limit,
-                               pred_info, succ_info)
+        return pred_info, succ_info
+
+    def _snapshot(self) -> np.ndarray:
+        return encode_snapshot(self.sim.sample(), self.cfg.n_nodes,
+                               self.cfg.sub_limit, *self.pair_info())
 
     def _advance(self, dt: float) -> None:
         """Advance in sampling-interval steps, recording history."""

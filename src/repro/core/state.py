@@ -13,6 +13,10 @@ Variable map (paper §4.1):
   var35-38    predecessor: size, limit, queue time, elapsed runtime
   var39-40    successor:   size, limit
 
+Columns 0-33 describe the cluster and 34-39 the chain's own pair:
+``encode_snapshot`` is ``encode_cluster`` followed by ``encode_pair``,
+so chains that share one simulator at one instant share the first part.
+
 All features are normalized (sizes by cluster nodes, times by the 48 h
 limit, counts by /100) so one trained network transfers across clusters
 only in *shape* — per the paper, models must be trained per cluster.
@@ -60,10 +64,11 @@ def _pcts(vals, scale: float) -> np.ndarray:
     return (out / scale).astype(np.float32)
 
 
-def encode_snapshot(sample: Dict, n_nodes: int, limit: float,
-                    pred: Optional[Dict] = None,
-                    succ: Optional[Dict] = None) -> np.ndarray:
-    """sample: SlurmSimulator.sample() output -> (40,) float32."""
+def encode_cluster(sample: Dict, n_nodes: int, limit: float) -> np.ndarray:
+    """The cluster part of a snapshot: sample -> (40,) float32 with
+    columns 0-33 (queue and running populations) filled and the pair
+    columns 34-39 zero. Every chain that reads one simulator at one
+    instant shares this part."""
     v = np.zeros(STATE_DIM, np.float32)
     v[0] = sample["n_queued"] / 100.0
     v[1:6] = _pcts(sample["queued_sizes"], n_nodes)
@@ -77,6 +82,16 @@ def encode_snapshot(sample: Dict, n_nodes: int, limit: float,
         v[23] = float(rs.std()) / n_nodes
     v[24:29] = _pcts(sample["running_elapsed"], limit)
     v[29:34] = _pcts(sample["running_limits"], limit)
+    return v
+
+
+def encode_pair(v: np.ndarray, n_nodes: int, limit: float,
+                pred: Optional[Dict] = None,
+                succ: Optional[Dict] = None) -> np.ndarray:
+    """The pair part of a snapshot: write the predecessor (34-37) and
+    successor (38-39) columns of ``v`` in place and return it. Absent
+    infos write zeros, so a row may be reused across chains."""
+    v[34:40] = 0.0
     if pred:
         v[34] = pred.get("size", 0) / n_nodes
         v[35] = pred.get("limit", 0) / limit
@@ -86,6 +101,15 @@ def encode_snapshot(sample: Dict, n_nodes: int, limit: float,
         v[38] = succ.get("size", 0) / n_nodes
         v[39] = succ.get("limit", 0) / limit
     return v
+
+
+def encode_snapshot(sample: Dict, n_nodes: int, limit: float,
+                    pred: Optional[Dict] = None,
+                    succ: Optional[Dict] = None) -> np.ndarray:
+    """sample: SlurmSimulator.sample() output -> (40,) float32: the
+    cluster part, then the pair part."""
+    return encode_pair(encode_cluster(sample, n_nodes, limit), n_nodes,
+                       limit, pred, succ)
 
 
 def _segment_pcts(vals: np.ndarray, off: np.ndarray, scale: float,

@@ -47,7 +47,7 @@ from repro.core.control import (JOURNAL_VERSION, ChainLane, ChainResult,
                                 RetryPolicy)
 from repro.core.provisioner import EnvConfig, ReplayCheckpointCache
 from repro.core.reward import shape_reward
-from repro.core.state import StateHistory
+from repro.core.state import StateHistory, encode_cluster, encode_pair
 from repro.sim.multitenant import (MultiTenantSim, TenantOutcome,
                                    make_tenant_chain)
 from repro.sim.simulator import SlurmSimulator
@@ -258,8 +258,8 @@ class CoSimWorld:
                 lane.env.chain = chain
                 lane.env.pred = self.world.submit_pred(lane.tenant, chain)
             self.world.start_preds()
+            self._push_snapshots(self.lanes)
             for lane in self.lanes:
-                lane.env.hist.push(lane.env._snapshot())
                 lane.obs = lane.env.obs()
         self._rehydrate(bodies)
 
@@ -269,6 +269,21 @@ class CoSimWorld:
         vec = self.lanes[0].env._snapshot()
         for lane in self.lanes:
             lane.env.hist.push(vec)
+
+    def _push_snapshots(self, lanes: Sequence[CoSimChainLane]) -> None:
+        """One history push into each of ``lanes`` at the current instant.
+        Every lane reads the world's one simulator, so the cluster columns
+        are encoded once and each lane writes only its pair columns into
+        the shared row (``push`` copies)."""
+        if not lanes:
+            return
+        cfg = self.cfg
+        with telemetry.span("state.snapshot"):
+            row = encode_cluster(self.world.sim.sample(), cfg.n_nodes,
+                                 cfg.sub_limit)
+        for lane in lanes:
+            lane.env.hist.push(encode_pair(row, cfg.n_nodes, cfg.sub_limit,
+                                           *lane.env.pair_info()))
 
     # ---------------------------------------------------------- rehydrate
     def _rehydrate(self, bodies: List[List[dict]]) -> None:
@@ -333,10 +348,9 @@ class CoSimWorld:
                 self.lanes[out.tenant]._finish_link(out)
             self.round += 1
             with telemetry.span("state.encode"):
-                for t in np.flatnonzero(waiting):
-                    lane = self.lanes[int(t)]
-                    if not lane.done:
-                        lane.env.hist.push(lane.env._snapshot())
+                self._push_snapshots(
+                    [self.lanes[t] for t in np.flatnonzero(waiting)
+                     if not self.lanes[t].done])
                 for lane in self.lanes:
                     if not lane.done and not w.pending[lane.tenant]:
                         lane.obs = lane.env.obs()

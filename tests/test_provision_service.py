@@ -3,7 +3,9 @@ batching equivalence, kill-at-arbitrary-point recovery, circuit-breaker
 degradation, deadline-aware load shedding and graceful drain. All chaos
 is seeded and clocks/sleeps are injected — no wall-clock waits.
 """
+import dataclasses
 import time
+import zlib
 
 import numpy as np
 import pytest
@@ -11,8 +13,11 @@ import pytest
 from repro.core import (ChainDriver, CircuitBreaker, EnvConfig,
                         FallbackPolicy, ReactivePolicy,
                         ReplayCheckpointCache, RetryPolicy)
+from repro.core.state import encode_snapshot
 from repro.serve import ProvisionService, ServiceConfig
-from repro.sim import get_fault_spec, synthesize_trace
+from repro.serve.cosim import CoSimChainLane, CoSimWorld
+from repro.sim import FaultPlan, get_fault_spec, synthesize_trace
+from repro.sim.faults import FAIL, REPAIR
 from repro.sim.trace import V100
 from repro.train.fault import PreemptionGuard
 
@@ -440,3 +445,191 @@ def test_cosim_spans_count_the_service_and_change_nothing(world, tmp_path):
     assert on_actions == off_actions and on_actions
     assert _schedules(on) == _schedules(off)
     telemetry.reset()
+
+
+# ------------------------------------------- shared snapshot encoding
+def _pair_row(sim, cfg, pred):
+    """One lane's snapshot row built on its own, as the scalar
+    environment builds it: the full encode of the simulator's sample
+    with the lane's predecessor and successor infos."""
+    info = None
+    if pred is not None:
+        info = {"size": pred.n_nodes, "limit": pred.time_limit,
+                "queue_time": max(pred.wait_time, 0.0),
+                "elapsed": (max(sim.now - pred.start_time, 0.0)
+                            if pred.start_time >= 0 else 0.0)}
+    return encode_snapshot(sim.sample(), cfg.n_nodes, cfg.sub_limit, info,
+                           {"size": cfg.chain_nodes, "limit": cfg.sub_limit})
+
+
+class _PerLaneWorld(CoSimWorld):
+    """The reference: every lane encodes the whole snapshot itself, and
+    counts the pushes made while its predecessor was requeued."""
+    requeued_pushes = 0
+
+    def _push_snapshots(self, lanes):
+        for lane in lanes:
+            pred = lane.env.pred
+            _PerLaneWorld.requeued_pushes += int(
+                pred is not None and pred.start_time < 0)
+            lane.env.hist.push(_pair_row(lane.env.sim, self.cfg, pred))
+
+
+@pytest.fixture(scope="module", params=["fault-free", "faulty", "outage"])
+def snap_world(request, world):
+    """The fault-free cluster, the ``faulty`` profile, and an outage of
+    every node from 1 h to 4 h after the episodes' start, which requeues
+    every tenant's running predecessor."""
+    if request.param == "faulty":
+        return ("faulty",) + world
+    jobs, cfg, _ = world
+    cfg = dataclasses.replace(cfg, faults=None)
+    if request.param == "outage":
+        probe = CoSimWorld(jobs, cfg, 1, seed=SEED,
+                           cache=ReplayCheckpointCache(jobs, cfg.n_nodes))
+        CoSimChainLane(jobs, cfg, probe, 0, links=LINKS, seed=SEED)
+        probe.begin()
+        n = cfg.n_nodes
+        cfg = dataclasses.replace(cfg, faults=FaultPlan(
+            np.array([probe.t0 + 1 * HOUR, probe.t0 + 4 * HOUR]),
+            np.array([FAIL, REPAIR]), np.array([n, n])))
+    return (request.param, jobs, cfg,
+            ReplayCheckpointCache(jobs, cfg.n_nodes, faults=cfg.faults))
+
+
+def _lanes_equal(a, b):
+    for la, lb in zip(a.lanes, b.lanes):
+        np.testing.assert_array_equal(la.env.hist.matrix(),
+                                      lb.env.hist.matrix())
+        assert (la.obs is None) == (lb.obs is None)
+        if la.obs is not None:
+            for key in la.obs:
+                np.testing.assert_array_equal(la.obs[key], lb.obs[key], key)
+
+
+@pytest.mark.parametrize("tenants", [1, 3, 8])
+def test_cosim_shared_snapshot_matches_per_lane_encode(snap_world, tenants):
+    """The world encodes the cluster once per instant and each lane adds
+    its pair columns: after ``begin`` and after every ``advance_round``
+    each lane's history and observation equal, bit for bit, those of a
+    world whose lanes each encode ``sim.sample()`` with their own
+    predecessor and successor infos."""
+    name, jobs, cfg, cache = snap_world
+
+    def build(cls):
+        w = cls(jobs, cfg, tenants, seed=SEED, cache=cache)
+        for i in range(tenants):
+            CoSimChainLane(jobs, cfg, w, i, links=LINKS, seed=SEED + i,
+                           retry=_retry_factory(i), cache=cache)
+        w.begin()
+        return w
+
+    _PerLaneWorld.requeued_pushes = 0
+    new, ref = build(CoSimWorld), build(_PerLaneWorld)
+    _lanes_equal(new, ref)
+    rng = np.random.default_rng(tenants)
+    rounds = 0
+    while not all(lane.done for lane in new.lanes):
+        for ln, lr in zip(new.lanes, ref.lanes):
+            assert ln.awaiting == lr.awaiting
+            if ln.awaiting:
+                a = int(rng.random() < 0.1)
+                ln.apply(a)
+                lr.apply(a)
+        new.advance_round()
+        ref.advance_round()
+        _lanes_equal(new, ref)
+        rounds += 1
+        assert rounds < 10_000
+    assert all(lane.done for lane in ref.lanes)
+    assert [lane.outcomes for lane in new.lanes] == \
+        [lane.outcomes for lane in ref.lanes]
+    if name == "outage":
+        # the pair columns of a requeued predecessor were exercised
+        assert _PerLaneWorld.requeued_pushes > 0
+
+
+class _Hashing(ReactivePolicy):
+    """Submits on a hash of each observation's bytes: a change of any
+    bit of a lane's observation changes its actions."""
+
+    def __init__(self):
+        super().__init__()
+        self.actions = []
+
+    def act_batch(self, obs):
+        acts = np.array([zlib.crc32(m.tobytes() + r.tobytes()) % 6 == 0
+                         for m, r in zip(obs["matrix"],
+                                         np.asarray(obs["pred_remaining"],
+                                                    np.float64))], np.int64)
+        self.actions.append(acts.tolist())
+        return acts
+
+
+@pytest.mark.parametrize("tenants", [1, 3, 8])
+def test_cosim_shared_snapshot_serves_the_same_service(snap_world, tenants,
+                                                      tmp_path,
+                                                      monkeypatch):
+    """A short co-sim service run serves the same actions, writes the same
+    journals and ends with the same schedules as one whose lanes each
+    encode their whole snapshot."""
+    _, jobs, cfg, cache = snap_world
+
+    def serve(name):
+        policy = _Hashing()
+        jdir = tmp_path / name
+        res = ProvisionService(
+            jobs, cfg, policy,
+            svc=ServiceConfig(tenants=tenants, links=LINKS, max_batch=4,
+                              co_sim=True),
+            seed=SEED, journal_dir=str(jdir), cache=cache,
+            retry_factory=_retry_factory).run()
+        assert res.reason == "completed"
+        journals = {p.name: p.read_bytes() for p in sorted(jdir.iterdir())}
+        return res, policy.actions, journals
+
+    new, new_actions, new_journals = serve("new")
+    monkeypatch.setattr(CoSimWorld, "_push_snapshots",
+                        _PerLaneWorld._push_snapshots)
+    ref, ref_actions, ref_journals = serve("ref")
+    assert new_actions == ref_actions
+    assert any(1 in batch for batch in new_actions)
+    assert len(new_journals) == tenants
+    assert new_journals == ref_journals
+    assert _schedules(new) == _schedules(ref)
+
+
+def test_cosim_one_snapshot_span_per_shared_instant(world):
+    """``state.snapshot`` is recorded once per round whose advance pushed
+    a history row, plus once for the start's inject, and recording
+    changes nothing served."""
+    from repro import telemetry
+
+    def serve():
+        policy = _Hashing()
+        svc = _co_service(world, policy=policy)
+        advance = svc.cosim.advance_round
+        pushed = []
+
+        def advance_round():
+            before = [lane.env.hist._pos for lane in svc.cosim.lanes]
+            advance()
+            pushed.append(before != [lane.env.hist._pos
+                                     for lane in svc.cosim.lanes])
+
+        svc.cosim.advance_round = advance_round
+        return svc.run(), policy.actions, pushed
+
+    telemetry.reset()
+    off, off_actions, _ = serve()
+    with telemetry.capture():
+        t0 = time.perf_counter()
+        on, on_actions, pushed = serve()
+        t1 = time.perf_counter()
+    tot = telemetry.totals(t0, t1)
+    telemetry.reset()
+    assert on.reason == "completed" and any(pushed) and not all(pushed)
+    assert tot["cosim.advance"].count == len(pushed)
+    assert tot["state.snapshot"].count == sum(pushed) + 1
+    assert on_actions == off_actions
+    assert _schedules(on) == _schedules(off)

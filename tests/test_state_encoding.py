@@ -5,10 +5,12 @@ falling back to the deterministic tests/_shims shim), plus the flat
 simulator snapshots.
 """
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.state import (STATE_DIM, encode_sample_batch,
-                              encode_snapshot, encode_snapshots)
+from repro.core.state import (STATE_DIM, encode_cluster, encode_pair,
+                              encode_sample_batch, encode_snapshot,
+                              encode_snapshots)
 from repro.sim import SlurmSimulator, sample_batch, synthesize_trace
 from repro.sim.trace import V100
 
@@ -60,6 +62,54 @@ def test_encode_snapshots_bit_identical(shape, seed, with_pred, with_succ):
                               preds[b] if preds else None,
                               succs[b] if succs else None)
         np.testing.assert_array_equal(batch[b], ref, err_msg=f"lane {b}")
+
+
+def _random_pair(rng):
+    return ({"size": int(rng.integers(1, 9)),
+             "limit": float(rng.uniform(60.0, LIMIT)),
+             "queue_time": float(rng.uniform(0, LIMIT)),
+             "elapsed": float(rng.uniform(0, LIMIT))},
+            {"size": int(rng.integers(1, 9)),
+             "limit": float(rng.uniform(60.0, LIMIT))})
+
+
+def _check_cluster_then_pair(nq, nr, seed, with_pred, with_succ):
+    rng = np.random.default_rng(seed)
+    sample = make_sample(rng, nq, nr)
+    pred, succ = _random_pair(rng)
+    pred = pred if with_pred else None
+    succ = succ if with_succ else None
+    ref = encode_snapshot(sample, 88, LIMIT, pred, succ)
+    cluster = encode_cluster(sample, 88, LIMIT)
+    assert cluster.dtype == np.float32 and cluster.shape == (STATE_DIM,)
+    assert not cluster[34:].any()
+    np.testing.assert_array_equal(cluster[:34], ref[:34])
+    row = encode_pair(cluster.copy(), 88, LIMIT, pred, succ)
+    assert row.tobytes() == ref.tobytes()
+    # written over a row that held another chain's pair columns
+    other = encode_pair(cluster.copy(), 88, LIMIT, *_random_pair(rng))
+    assert encode_pair(other, 88, LIMIT, pred, succ).tobytes() \
+        == ref.tobytes()
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.integers(0, 9), st.integers(0, 9), st.integers(0, 2**31 - 1),
+       st.booleans(), st.booleans())
+def test_encode_snapshot_is_cluster_then_pair(nq, nr, seed, with_pred,
+                                              with_succ):
+    """encode_snapshot equals the cluster part followed by the pair part,
+    bit for bit, and the pair part gives the same row when written over
+    a row that held another chain's pair columns."""
+    _check_cluster_then_pair(nq, nr, seed, with_pred, with_succ)
+
+
+@pytest.mark.parametrize("nq,nr,with_pred", [(0, 4, True), (4, 0, True),
+                                             (0, 0, True), (3, 3, False),
+                                             (0, 0, False)])
+def test_encode_snapshot_is_cluster_then_pair_edges(nq, nr, with_pred):
+    """The same identity on an empty queue, nothing running and no
+    predecessor."""
+    _check_cluster_then_pair(nq, nr, 7, with_pred, True)
 
 
 def test_encode_snapshots_all_empty():
