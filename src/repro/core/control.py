@@ -523,7 +523,8 @@ class ChainLane:
                 self.done = True
         else:
             env._advance(env.cfg.interval)
-        self.obs = env.obs()
+        with telemetry.span("env.encode"):
+            self.obs = env.obs()
 
     def result(self, reason: str) -> ChainResult:
         """Materialize the lane's outcome (projecting the live tail link

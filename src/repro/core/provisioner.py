@@ -38,12 +38,14 @@ encoder/ring reproduce the scalar per-lane push sequences exactly.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import copy
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import telemetry
 from repro.sim.faults import FaultPlan
 from repro.sim.simulator import SlurmSimulator, sample_batch, step_batch
 from repro.sim.trace import Job
@@ -73,6 +75,13 @@ class EnvConfig:
     # background timeline) where provably exact, falling back to real
     # forks otherwise; False forces the classic fork-per-lane path
     differential: bool = True
+
+
+_QUIET = contextlib.nullcontext()
+
+
+def _untraced(name: str):
+    return _QUIET
 
 
 class ProvisionEnv:
@@ -115,14 +124,21 @@ class ProvisionEnv:
         return encode_snapshot(self.sim.sample(), self.cfg.n_nodes,
                                self.cfg.sub_limit, *self.pair_info())
 
-    def _advance(self, dt: float) -> None:
-        """Advance in sampling-interval steps, recording history."""
+    def _advance(self, dt: float, traced: bool = True) -> None:
+        """Advance in sampling-interval steps, recording history. Each
+        step is an ``env.advance`` span and each push an ``env.encode``
+        span; ``traced=False`` (the warm-up's fill, inside ``env.warmup``)
+        records neither."""
+        span = telemetry.span if traced else _untraced
         end = self.sim.now + dt
         while self.sim.now + self.cfg.interval <= end:
-            self.sim.step(self.cfg.interval)
-            self.hist.push(self._snapshot())
+            with span("env.advance"):
+                self.sim.step(self.cfg.interval)
+            with span("env.encode"):
+                self.hist.push(self._snapshot())
         if self.sim.now < end:
-            self.sim.step(end - self.sim.now)
+            with span("env.advance"):
+                self.sim.step(end - self.sim.now)
 
     def obs(self) -> Dict:
         m = self.hist.matrix()
@@ -144,16 +160,19 @@ class ProvisionEnv:
     def reset(self, t_start: Optional[float] = None) -> Dict:
         lo, hi = self._t_start_range
         t0 = t_start if t_start is not None else float(self.rng.uniform(lo, hi))
-        if self.cache is not None:
-            # warm path: fork the shared background replay at the window
-            # head instead of re-replaying the trace from t=0 (checkpoint
-            # forks are bit-identical to a fresh replay — cache contract)
-            sim = self.cache.fork_at(self.warmup_point(t0))
-        else:
-            sim = SlurmSimulator(self.cfg.n_nodes, mode="fast",
-                                 faults=self.cfg.faults)
-            sim.load([copy.copy(j) for j in self.trace])
-        return self._begin_episode(sim, t0)
+        with telemetry.span("env.reset"):
+            with telemetry.span("env.fork"):
+                if self.cache is not None:
+                    # warm path: fork the shared background replay at the
+                    # window head instead of re-replaying the trace from
+                    # t=0 (checkpoint forks are bit-identical to a fresh
+                    # replay — cache contract)
+                    sim = self.cache.fork_at(self.warmup_point(t0))
+                else:
+                    sim = SlurmSimulator(self.cfg.n_nodes, mode="fast",
+                                         faults=self.cfg.faults)
+                    sim.load([copy.copy(j) for j in self.trace])
+            return self._begin_episode(sim, t0)
 
     def _begin_episode(self, sim: SlurmSimulator, t0: float) -> Dict:
         """Start an episode at t0 on ``sim`` (fresh, or forked at/before
@@ -163,9 +182,10 @@ class ProvisionEnv:
         self.pred = None
         self.succ = None
         # warm up: run to the history-window start, then fill the window
-        self.sim.run_until(self.warmup_point(t0))
-        self.hist.push(self._snapshot())
-        self._advance(max(t0 - self.sim.now, 0.0))
+        with telemetry.span("env.warmup"):
+            self.sim.run_until(self.warmup_point(t0))
+            self.hist.push(self._snapshot())
+            self._advance(max(t0 - self.sim.now, 0.0), traced=False)
         # submit + start the predecessor
         self.chain = SubJobChain(user_id=int(self.rng.integers(1000, 2000)),
                                  n_nodes=self.cfg.chain_nodes,

@@ -633,3 +633,73 @@ def test_cosim_one_snapshot_span_per_shared_instant(world):
     assert tot["state.snapshot"].count == sum(pushed) + 1
     assert on_actions == off_actions
     assert _schedules(on) == _schedules(off)
+
+
+# ------------------------------------------------ solo environment spans
+@pytest.mark.parametrize("tenants", [1, 3, 6])
+def test_solo_env_spans_count_per_tenant(world, tenants):
+    """In the solo service a start records one ``env.reset`` (with one
+    ``env.fork`` and one ``env.warmup``) per tenant and no step of its
+    warm-up; a round in which every tenant waits records one
+    ``env.advance`` and one history-push ``env.encode`` per tenant, and
+    each decision one ``env.encode`` for its observation refresh.
+    Recording changes nothing served."""
+    from repro import telemetry
+    svc_cfg = ServiceConfig(tenants=tenants, links=LINKS, max_batch=4)
+
+    def serve(max_rounds=None):
+        policy = _Hashing()
+        svc = _service(world, policy=FallbackPolicy(ReactivePolicy()) if
+                       max_rounds else policy, svc=svc_cfg)
+        t0 = time.perf_counter()
+        svc.start()
+        t1 = time.perf_counter()
+        res = svc.run(max_rounds=max_rounds)
+        return svc, res, policy.actions, (t0, t1, time.perf_counter())
+
+    telemetry.reset()
+    with telemetry.capture():
+        svc, res, _, (t0, t1, t2) = serve(max_rounds=1)
+    start, round_ = telemetry.totals(t0, t1), telemetry.totals(t1, t2)
+    assert res.n_rounds == 1 and res.n_decisions == tenants
+    assert all(not lane.outcomes for lane in svc.lanes)     # every one waited
+    for name in ("env.reset", "env.fork", "env.warmup"):
+        assert start[name].count == tenants and round_[name].count == 0
+    assert start["env.advance"].count == start["env.encode"].count == 0
+    assert round_["env.advance"].count == tenants
+    assert round_["env.encode"].count == 2 * tenants
+    assert round_["lane.apply"].count == tenants
+
+    off_svc, off, off_actions, _ = serve()
+    with telemetry.capture():
+        on_svc, on, on_actions, (t0, _, t2) = serve()
+    tot = telemetry.totals(t0, t2)
+    telemetry.reset()
+    submits = sum(len(lane.outcomes) for lane in on_svc.lanes)
+    assert on.reason == "completed" and 0 < submits < on.n_decisions
+    assert tot["env.advance"].count == on.n_decisions - submits
+    assert tot["env.encode"].count == 2 * on.n_decisions - submits
+    assert on_actions == off_actions and on_actions
+    assert _schedules(on) == _schedules(off)
+
+
+def test_cosim_records_no_env_spans(world):
+    """The co-sim round passes none of the solo environment's spans, and
+    its own spans keep their counts: one ``state.encode`` and one
+    ``sim.advance`` per ``cosim.advance``."""
+    from repro import telemetry
+    telemetry.reset()
+    with telemetry.capture():
+        t0 = time.perf_counter()
+        res = _co_service(world, policy=_Hashing()).run()
+        t1 = time.perf_counter()
+    tot = telemetry.totals(t0, t1)
+    telemetry.reset()
+    assert res.reason == "completed" and tot["cosim.advance"].count > 0
+    for name in ("env.reset", "env.fork", "env.warmup", "env.advance",
+                 "env.encode"):
+        assert tot[name].count == 0, name
+    assert tot["state.encode"].count == tot["sim.advance"].count \
+        == tot["cosim.advance"].count
+    assert tot["cosim.inject"].count == 1
+    assert tot["state.snapshot"].count >= 1
