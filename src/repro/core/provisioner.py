@@ -48,6 +48,7 @@ import numpy as np
 from repro import telemetry
 from repro.sim.faults import FaultPlan
 from repro.sim.simulator import SlurmSimulator, sample_batch, step_batch
+from repro.sim.timeline import BackgroundTimeline, Placement
 from repro.sim.trace import Job
 from repro.sim.workload import SubJobChain, pair_outcome
 from .reward import RewardConfig, shape_reward
@@ -57,6 +58,10 @@ from .state import (SAMPLE_INTERVAL, STATE_DIM, StateHistory,
 
 HOUR = 3600.0
 DAY = 24 * HOUR
+# a cache's timeline grows a week of simulated time past what a reset
+# needs, so the frontier's replay and the timeline's rebuild are paid
+# once per week of trace, not once per reset
+TIMELINE_CHUNK = 7 * DAY
 
 
 @dataclasses.dataclass
@@ -71,9 +76,9 @@ class EnvConfig:
     # deterministic fault schedule threaded into every simulator the env
     # (or its checkpoint cache) builds; None == fault-free
     faults: Optional[FaultPlan] = None
-    # serve vector-env resets from the differential engine (the immutable
-    # background timeline) where provably exact, falling back to real
-    # forks otherwise; False forces the classic fork-per-lane path
+    # serve resets (scalar and vector) from the differential engine (the
+    # immutable background timeline) where provably exact, falling back
+    # to real forks otherwise; False forces the classic fork path
     differential: bool = True
 
 
@@ -153,6 +158,14 @@ class ProvisionEnv:
         }
 
     # ------------------------------------------------------------ episode
+    def _new_chain(self) -> SubJobChain:
+        """The episode's chain: its user, then its first job id, drawn
+        in that order from the env's rng."""
+        return SubJobChain(user_id=int(self.rng.integers(1000, 2000)),
+                           n_nodes=self.cfg.chain_nodes,
+                           sub_limit=self.cfg.sub_limit,
+                           next_id=int(self.rng.integers(10**6, 10**7)))
+
     def warmup_point(self, t0: float) -> float:
         """The instant an episode's history window begins (fork point)."""
         return max(t0 - self.cfg.history * self.cfg.interval, 0.0)
@@ -161,6 +174,11 @@ class ProvisionEnv:
         lo, hi = self._t_start_range
         t0 = t_start if t_start is not None else float(self.rng.uniform(lo, hi))
         with telemetry.span("env.reset"):
+            if (self.cache is not None and self.cfg.differential
+                    and np.isfinite(t0)):
+                tl = self.cache.timeline(until=t0)
+                if t0 < tl.valid_until:
+                    return self._begin_differential(tl, t0)
             with telemetry.span("env.fork"):
                 if self.cache is not None:
                     # warm path: fork the shared background replay at the
@@ -187,13 +205,45 @@ class ProvisionEnv:
             self.hist.push(self._snapshot())
             self._advance(max(t0 - self.sim.now, 0.0), traced=False)
         # submit + start the predecessor
-        self.chain = SubJobChain(user_id=int(self.rng.integers(1000, 2000)),
-                                 n_nodes=self.cfg.chain_nodes,
-                                 sub_limit=self.cfg.sub_limit,
-                                 next_id=int(self.rng.integers(10**6, 10**7)))
+        self.chain = self._new_chain()
         self.pred = self.chain.make_sub(0, self.sim.now)
         self.sim.submit(self.pred)
         self.sim.run_until_started(self.pred)
+        self._fc0 = (self.sim.n_node_failures, self.sim.n_requeues)
+        self.hist.push(self._snapshot())
+        return self.obs()
+
+    def _begin_differential(self, tl: BackgroundTimeline, t0: float) -> Dict:
+        """``_begin_episode``'s episode, served off the background
+        timeline: the history window in one gather of its instants, the
+        predecessor placed by proof, and a simulator forked only where
+        the placement ends. Bit-identical to the fork path."""
+        self.hist = StateHistory(self.cfg.history)
+        self.succ = None
+        with telemetry.span("env.warmup"):
+            # the fork path's instants, float for float: the window head,
+            # one per whole interval up to t0, then the partial step
+            wp = self.warmup_point(t0)
+            end = wp + max(t0 - wp, 0.0)
+            ts = [wp]
+            while ts[-1] + self.cfg.interval <= end:
+                ts.append(ts[-1] + self.cfg.interval)
+            t0 = ts[-1] + (end - ts[-1]) if ts[-1] < end else ts[-1]
+            sb = tl.sample_lanes(np.array(ts, np.float64))
+            succ = np.array([self.cfg.chain_nodes, self.cfg.sub_limit],
+                            np.float64)
+            rows = encode_sample_batch(sb, self.cfg.n_nodes,
+                                       self.cfg.sub_limit, None,
+                                       np.broadcast_to(succ, (len(ts), 2)))
+            for row in rows:
+                self.hist.push(row)
+        self.chain = self._new_chain()
+        self.pred = self.chain.make_sub(0, t0)
+        with telemetry.span("env.place"):
+            pl = place_predecessor(self.cache, tl, self.pred,
+                                   self.cfg.interval)
+        with telemetry.span("env.fork"):
+            self.sim = materialize(self.cache, pl, self.pred)
         self._fc0 = (self.sim.n_node_failures, self.sim.n_requeues)
         self.hist.push(self._snapshot())
         return self.obs()
@@ -271,6 +321,10 @@ class ReplayCheckpointCache:
     other interior checkpoint is dropped (density halves, coverage and the
     endpoints stay), keeping the worst-case warm replay bounded while the
     memory stays under the configured budget.
+
+    The frontier records every scheduling pass from t=0 as it replays, so
+    the background timeline (``timeline()``) is a by-product of the
+    replay that forks pay for anyway, valid as far as it has run.
     """
 
     def __init__(self, trace: Sequence[Job], n_nodes: int, mode: str = "fast",
@@ -283,6 +337,10 @@ class ReplayCheckpointCache:
         self.faults = faults
         self._frontier = SlurmSimulator(n_nodes, mode=mode, faults=faults)
         self._frontier.load([copy.copy(j) for j in trace])
+        self._rec = BackgroundTimeline.record(self._frontier)
+        self._first_fault = (float(faults.times[0])
+                             if faults is not None and len(faults)
+                             else float("inf"))
         self._times: List[float] = []
         self._sims: List[SlurmSimulator] = []
         self._bytes: List[int] = []
@@ -331,21 +389,23 @@ class ReplayCheckpointCache:
         sim.run_until(t)
         return False, sim
 
-    def timeline(self):
+    def timeline(self, until: Optional[float] = None) -> BackgroundTimeline:
         """The immutable ``BackgroundTimeline`` of this cache's replay,
-        built lazily on first call (counted as one miss; every reuse is a
-        hit). On a pristine frontier the recording drains the frontier
-        itself, leaving warm checkpoints behind for later forks; otherwise
-        a throwaway replay records (the replay engine is deterministic, so
-        both record the same timeline)."""
-        if self._timeline is not None:
+        valid past ``until`` (or up to the first fault, where that comes
+        first). Without ``until`` the frontier drains the whole trace.
+        A timeline that already covers the request is served again
+        (a hit); otherwise the frontier replays, with its checkpoints,
+        a ``TIMELINE_CHUNK`` past the request and the timeline is
+        rebuilt from its recording (a miss)."""
+        tl = self._timeline
+        if tl is not None and (tl.valid_until >= self._first_fault
+                               or (until is not None
+                                   and tl.valid_until > until)):
             self.hits += 1
-            return self._timeline
-        from repro.sim.timeline import BackgroundTimeline
+            return tl
         self.misses += 1
         fr = self._frontier
-        if fr.now == 0.0 and fr._sched_passes == 0 and not self._sims:
-            rec = BackgroundTimeline.record(fr)
+        if until is None:
             while True:
                 tn = fr._next_event_time()
                 if tn == float("inf"):
@@ -354,15 +414,11 @@ class ReplayCheckpointCache:
                 if not np.isfinite(t):
                     t = tn
                 self._advance_frontier(float(t))
-            sim = fr
         else:
-            sim = SlurmSimulator(fr.cluster.n_nodes, mode=fr.mode,
-                                 faults=self.faults)
-            sim.load([copy.copy(j) for j in self.trace])
-            rec = BackgroundTimeline.record(sim)
-            sim.run_to_completion()
-        self._timeline = BackgroundTimeline.from_recording(sim, rec,
-                                                           self.faults)
+            t = min(until + TIMELINE_CHUNK, self._first_fault)
+            if t > fr.now:
+                self._advance_frontier(t)
+        self._timeline = BackgroundTimeline.of(fr, self._rec, self.faults)
         return self._timeline
 
     def _advance_frontier(self, t: float) -> None:
@@ -385,6 +441,55 @@ class ReplayCheckpointCache:
             drop = range(len(self._sims) - 2, 0, -2)   # every other interior
             for k in drop:
                 del self._times[k], self._sims[k], self._bytes[k]
+
+
+def place_predecessor(cache: ReplayCheckpointCache, tl: BackgroundTimeline,
+                      pred: Job, interval: float) -> Placement:
+    """Where the episode's predecessor ``pred``, submitted at its
+    ``submit_time``, starts against the background: ``tl.place``'s proof,
+    proved again on a longer recording while it runs into the reach of
+    the one it has (``Placement.exhausted``)."""
+    while True:
+        pl = tl.place(pred.submit_time, pred.n_nodes, pred.time_limit,
+                      pred.runtime, pred.job_id, interval)
+        if not pl.exhausted:
+            return pl
+        tl = cache.timeline(until=tl.valid_until)
+
+
+def served(pl: Placement, t0: float) -> bool:
+    """True where the placement spared the replay of the predecessor's
+    queue wait: a proved start, or a cascade synced past t0."""
+    return pl.kind == "start" or (pl.kind == "cascade" and pl.t > t0)
+
+
+def materialize(cache: ReplayCheckpointCache, pl: Placement,
+                pred: Job) -> SlurmSimulator:
+    """The simulator of an episode at its predecessor's start, forked off
+    ``cache`` where the placement ``pl`` ends (differential resets,
+    scalar and vector)."""
+    t0 = pred.submit_time
+    if pl.kind == "start":
+        # proved: the job starts at pl.t without displacing any
+        # background start — fork the background there and splice the
+        # job in at its in-pass position
+        sim = cache.fork_quiet(pl.t)
+        sim.adopt_running(pred, pl.t, pl.pass_pos, pl.pass_size)
+    elif served(pl, t0):
+        # provable cascade past t0: sync a real fork at the last
+        # verified-inert instant with the job queued (original submit
+        # time — age priority survives)
+        sim = cache.fork_quiet(pl.t)
+        sim.adopt_queued(pred)
+        sim.run_until_started(pred)
+    else:
+        # cascade at the submission instant itself: replay the whole
+        # decision on a real fork from t0
+        with telemetry.span("env.replay"):
+            sim = cache.fork_quiet(t0)
+            sim.submit(pred)
+            sim.run_until_started(pred)
+    return sim
 
 
 class VectorProvisionEnv:
@@ -597,42 +702,15 @@ class VectorProvisionEnv:
         for i in range(self.batch):   # repro-static: ok[lane-loop] per-lane rng draws + placement materialization
             env = self.envs[i]
             t0i = float(t0_eff[i])
-            env.chain = SubJobChain(
-                user_id=int(env.rng.integers(1000, 2000)),
-                n_nodes=self.cfg.chain_nodes, sub_limit=self.cfg.sub_limit,
-                next_id=int(env.rng.integers(10**6, 10**7)))
+            env.chain = env._new_chain()
             env.pred = env.chain.make_sub(0, t0i)
             if diff[i]:
-                pl = tl.place(t0i, env.pred.n_nodes, env.pred.time_limit,
-                              env.pred.runtime, env.pred.job_id,
-                              self.cfg.interval)
-                if pl.kind == "start":
-                    # proved: the job starts at pl.t without displacing
-                    # any background start — fork the background there
-                    # and splice the job in at its in-pass position
-                    sim = self.cache.fork_quiet(pl.t)
-                    sim.adopt_running(env.pred, pl.t, pl.pass_pos,
-                                      pl.pass_size)
-                    st["starts"] += 1
-                    st["hit_intervals"] += int(pushes[i]) + pl.intervals
-                elif pl.kind == "cascade" and pl.t > t0i:
-                    # provable cascade past t0: sync a real fork at the
-                    # last verified-inert instant with the job queued
-                    # (original submit time — age priority survives)
-                    sim = self.cache.fork_quiet(pl.t)
-                    sim.adopt_queued(env.pred)
-                    sim.run_until_started(env.pred)
-                    st["cascades"] += 1
-                    st["hit_intervals"] += int(pushes[i]) + pl.intervals
-                else:
-                    # cascade at the submission instant itself: replay
-                    # the whole decision on a real fork from t0
-                    sim = self.cache.fork_quiet(t0i)
-                    sim.submit(env.pred)
-                    sim.run_until_started(env.pred)
-                    st["cascades"] += 1
-                    st["hit_intervals"] += int(pushes[i])
-                env.sim = sim
+                pl = place_predecessor(self.cache, tl, env.pred,
+                                       self.cfg.interval)
+                env.sim = materialize(self.cache, pl, env.pred)
+                st["starts" if pl.kind == "start" else "cascades"] += 1
+                st["hit_intervals"] += int(pushes[i]) + (
+                    pl.intervals if served(pl, t0i) else 0)
                 st["diff_lanes"] += 1
             else:
                 if env.sim.now < ends[i]:

@@ -2,10 +2,11 @@
 
 The vector env's episode tail re-simulates days of background backlog
 churn per lane, even though every lane is a one-job perturbation of the
-*same* cached background replay. This module materializes that replay
-once as an immutable ``BackgroundTimeline`` — frozen per-job event
-arrays (from ``SlurmSimulator.schedule_view()``) plus a scheduling-pass
-record captured by a ``PassRecorder`` during the replay — and then
+*same* cached background replay. This module materializes that replay,
+as far as it has run, as an immutable ``BackgroundTimeline`` — frozen
+per-job event arrays (from ``SlurmSimulator.schedule_view()``) plus a
+scheduling-pass record captured by a ``PassRecorder`` during the
+replay — and then
 answers the two questions an episode reset needs without touching a
 live simulator:
 
@@ -37,8 +38,9 @@ Soundness leans on engine invariants pinned by the tier-1 suite:
 unrecorded scheduling instants only ever follow a pass that recorded
 its blocking state (the no-op cache is decision-neutral and every
 full pass is recorded), completions always trigger recorded passes,
-and fault windows bound the valid region (``valid_until`` — everything
-at or past the first fault event falls back to real simulation).
+and fault windows and the replay's reach bound the valid region
+(``valid_until`` — everything at or past the first fault event or the
+last replayed instant falls back to real simulation).
 """
 from __future__ import annotations
 
@@ -124,24 +126,33 @@ class Placement:
     pass_size: int = 0       # total starts of that pass (incl. the job)
     run_pass: bool = False   # cascade at t0: re-run the submission pass
     intervals: int = 0       # verified decision intervals (hit-rate acct)
+    exhausted: bool = False  # the scan ran into the recorded reach: a
+    #                          longer recording may still decide
 
 
 class BackgroundTimeline:
     """Frozen replay of one background trace (see module docstring).
 
-    Build via ``BackgroundTimeline.from_recording`` after draining a
-    simulator that carried a ``PassRecorder``; all arrays are read-only
-    and shared across every lane/env holding the timeline.
+    Built from a simulator whose ``PassRecorder`` has recorded every
+    pass since t=0, at the instant ``reach`` it has replayed to (+inf
+    once drained): the timeline is the truth strictly before
+    ``valid_until``, the earlier of ``reach`` and the first fault. All
+    arrays are read-only copies or views, shared across every lane/env
+    holding the timeline; the recorder may go on recording.
     """
 
     def __init__(self, view: ScheduleView, rec: PassRecorder,
                  n_nodes: int, faults: Optional[FaultPlan],
-                 backfill: bool = True):
+                 backfill: bool = True, reach: float = _INF):
         self.n_nodes = int(n_nodes)
         self.backfill = bool(backfill)
         self.nav = max(self.n_nodes, 1)     # fault-free priority normalizer
-        self.valid_until = (float(faults.times[0])
-                            if faults is not None and len(faults) else _INF)
+        first_fault = (float(faults.times[0])
+                       if faults is not None and len(faults) else _INF)
+        self.valid_until = min(first_fault, float(reach))
+        # the region ends at the recording's reach, not at a fault: a
+        # placement that runs into it is undecided, not a cascade
+        self.open_ended = reach < first_fault
         # per-job arrays (read-only views from the recording simulator)
         self.sub = view.sub
         self.rt = view.runtime
@@ -184,17 +195,20 @@ class BackgroundTimeline:
     # ------------------------------------------------------------ building
     @staticmethod
     def record(sim: SlurmSimulator) -> PassRecorder:
-        """Attach a recorder to ``sim`` (the caller drains the replay)."""
+        """Attach a recorder to ``sim``, which has not run yet."""
+        assert sim.now == 0.0 and sim._sched_passes == 0
         rec = PassRecorder()
         sim._pass_rec = rec
         return rec
 
     @classmethod
-    def from_recording(cls, sim: SlurmSimulator, rec: PassRecorder,
-                       faults: Optional[FaultPlan]) -> "BackgroundTimeline":
-        sim._pass_rec = None
+    def of(cls, sim: SlurmSimulator, rec: PassRecorder,
+           faults: Optional[FaultPlan]) -> "BackgroundTimeline":
+        """The timeline ``rec`` has recorded on ``sim`` so far: up to
+        ``sim.now``, or all of it once no event is left."""
+        reach = _INF if sim._next_event_time() == _INF else sim.now
         return cls(sim.schedule_view(), rec, sim.cluster.n_nodes, faults,
-                   backfill=sim.backfill)
+                   backfill=sim.backfill, reach=reach)
 
     def _build_grid(self) -> None:
         """Coarse alive/queued snapshots every GRID_STEP: a query pays one
@@ -520,8 +534,10 @@ class BackgroundTimeline:
         ai = int(np.searchsorted(self.sub_sorted, t0, side="right"))
         taus = np.union1d(self.rec_t[ri:], self.sub_sorted[ai:])
         taus = taus[taus < self.valid_until]
+        exhausted = self.open_ended
         if taus.size > MAX_INSTANTS:
             taus = taus[:MAX_INSTANTS]
+            exhausted = False
         pos = 0
         while pos < taus.size:
             chunk = taus[pos:pos + 4096]
@@ -547,6 +563,7 @@ class BackgroundTimeline:
                 return Placement("cascade", t_sync, intervals=acct(t_sync))
             t_sync = tau
             pos += b + 1
-        # events exhausted (timeline horizon or fault boundary): hand the
-        # rest to a real fork synced at the last verified instant
-        return Placement("cascade", t_sync, intervals=acct(t_sync))
+        # events exhausted (recorded reach, trace end or fault boundary):
+        # hand the rest to a real fork synced at the last verified instant
+        return Placement("cascade", t_sync, intervals=acct(t_sync),
+                         exhausted=exhausted)

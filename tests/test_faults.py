@@ -278,8 +278,10 @@ def test_vector_matches_scalar_under_faults(faulty_world):
             if not was[i] and dones[i]:
                 vr[i] = r[i]
                 vinfos[i] = inf[i]
+    # the reference is the scalar fork path (no timeline)
+    fork_cfg = dataclasses.replace(cfg, differential=False)
     for i in range(B):
-        env = ProvisionEnv(jobs, cfg, seed=50 + i, cache=cache)
+        env = ProvisionEnv(jobs, fork_cfg, seed=50 + i, cache=cache)
         sobs = env.reset(t_start=float(t0s[i]))
         step = 0
         np.testing.assert_array_equal(vec[step]["matrix"][i],

@@ -129,8 +129,9 @@ def test_avg_wait_deque_matches_list_window():
 
 
 def test_scalar_env_cache_bit_identical(world):
-    """ProvisionEnv(cache=...) resets fork the shared replay instead of
-    re-replaying the trace head — observations and outcomes unchanged."""
+    """ProvisionEnv(cache=...) resets are served from the shared replay
+    (its timeline and checkpoints) instead of re-replaying the trace
+    head — observations and outcomes unchanged."""
     jobs, cfg, cache = world
     cold = ProvisionEnv(jobs, cfg, seed=3)
     warm = ProvisionEnv(jobs, cfg, seed=3, cache=cache)

@@ -683,6 +683,46 @@ def test_solo_env_spans_count_per_tenant(world, tenants):
     assert _schedules(on) == _schedules(off)
 
 
+def test_solo_service_starts_off_the_timeline_serve_the_same(tmp_path):
+    """A fault-free solo service whose tenant starts are served off the
+    background timeline answers the same actions, writes the same
+    journal bytes and ends with the same schedules as one whose starts
+    take the fork path (``differential=False``)."""
+    from repro import telemetry
+    jobs = synthesize_trace(V100, months=1, seed=5, load_scale=1.0)
+    cfg = EnvConfig(n_nodes=V100.n_nodes, history=12, interval=1800.0,
+                    sub_limit=8 * HOUR)
+
+    def serve(name, differential):
+        c = dataclasses.replace(cfg, differential=differential)
+        policy = _Hashing()
+        jdir = tmp_path / name
+        telemetry.reset()
+        with telemetry.capture():
+            t0 = time.perf_counter()
+            res = ProvisionService(
+                jobs, c, policy,
+                svc=ServiceConfig(tenants=TENANTS, links=LINKS, max_batch=4),
+                seed=SEED, journal_dir=str(jdir),
+                cache=ReplayCheckpointCache(jobs, c.n_nodes),
+                retry_factory=_retry_factory).run()
+            tot = telemetry.totals(t0, time.perf_counter())
+        telemetry.reset()
+        assert res.reason == "completed"
+        assert tot["env.reset"].count == TENANTS
+        journals = {p.name: p.read_bytes() for p in sorted(jdir.iterdir())}
+        return res, policy.actions, journals, tot["env.place"].count
+
+    new, new_actions, new_journals, placed = serve("timeline", True)
+    ref, ref_actions, ref_journals, forked = serve("fork", False)
+    assert (placed, forked) == (TENANTS, 0)
+    assert new_actions == ref_actions
+    assert any(1 in batch for batch in new_actions)
+    assert len(new_journals) == TENANTS
+    assert new_journals == ref_journals
+    assert _schedules(new) == _schedules(ref)
+
+
 def test_cosim_records_no_env_spans(world):
     """The co-sim round passes none of the solo environment's spans, and
     its own spans keep their counts: one ``state.encode`` and one
@@ -696,8 +736,8 @@ def test_cosim_records_no_env_spans(world):
     tot = telemetry.totals(t0, t1)
     telemetry.reset()
     assert res.reason == "completed" and tot["cosim.advance"].count > 0
-    for name in ("env.reset", "env.fork", "env.warmup", "env.advance",
-                 "env.encode"):
+    for name in ("env.reset", "env.fork", "env.warmup", "env.place",
+                 "env.replay", "env.advance", "env.encode"):
         assert tot[name].count == 0, name
     assert tot["state.encode"].count == tot["sim.advance"].count \
         == tot["cosim.advance"].count

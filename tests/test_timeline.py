@@ -6,17 +6,20 @@ surface (``make_env``/``make_vector_env`` factories, ``schedule_view``,
 ``resized``) must uphold its contracts.
 """
 import copy
+import time
 
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+import repro.core.provisioner as provisioner
+from repro import telemetry
 from repro.core import EnvConfig, ProvisionEnv
 from repro.core.provisioner import ReplayCheckpointCache
 from repro.sim import (FaultPlan, SlurmSimulator, get_fault_spec, make_env,
                        make_vector_env, synthesize_trace)
 from repro.sim.faults import FAIL, REPAIR
-from repro.sim.trace import V100
+from repro.sim.trace import V100, Job
 
 HOUR = 3600.0
 DAY = 24 * HOUR
@@ -205,3 +208,187 @@ def test_factory_lane_identity_and_resized(trace_cfg):
     vobs = venv.reset(t_starts=[ts, ts])
     sobs = make_env(jobs, cfg, seed=12, cache=cache).reset(t_start=ts)
     np.testing.assert_allclose(vobs["matrix"][1], sobs["matrix"], atol=1e-7)
+
+
+# ------------------------------------------- scalar differential resets
+def _cascade_at_t0_world():
+    """Four nodes; two running jobs leave one free. The blocked head (3
+    nodes) reserves the 5-h end, so the 1-node, 8-h job behind it cannot
+    backfill — until a 4-node predecessor outranks the head at t0 = 1 h
+    and moves the reservation to the 10-h end: the predecessor's
+    submission itself starts a background job."""
+    jobs = [Job(job_id=1, user_id=1, submit_time=0.0, runtime=5 * HOUR,
+                time_limit=5 * HOUR, n_nodes=2),
+            Job(job_id=2, user_id=1, submit_time=0.0, runtime=10 * HOUR,
+                time_limit=10 * HOUR, n_nodes=1),
+            Job(job_id=3, user_id=1, submit_time=60.0, runtime=HOUR,
+                time_limit=HOUR, n_nodes=3),
+            Job(job_id=4, user_id=1, submit_time=120.0, runtime=8 * HOUR,
+                time_limit=8 * HOUR, n_nodes=1),
+            Job(job_id=5, user_id=1, submit_time=30 * HOUR, runtime=HOUR,
+                time_limit=HOUR, n_nodes=1)]
+    cfg = EnvConfig(n_nodes=4, chain_nodes=4, sub_limit=12 * HOUR, history=2,
+                    interval=1800.0, warmup=HOUR)
+    return jobs, cfg, HOUR
+
+
+def _world(case):
+    """(trace, cfg, t_start) of one scalar-reset case."""
+    if case == "cascade-at-t0":
+        return _cascade_at_t0_world()
+    load = 0.5 if case == "start" else 1.0
+    jobs = synthesize_trace(V100, months=1, seed=5, load_scale=load)
+    plan = None
+    if case.startswith("faulty"):
+        # this seed's first fault falls 15.2 days in: starts on both
+        # sides of it, and one whose predecessor waits across it
+        plan = get_fault_spec("faulty").make_plan(
+            jobs[-1].submit_time + 3 * DAY, V100.n_nodes, seed=4)
+        assert 14 * DAY < plan.times[0] < 16 * DAY
+    cfg = EnvConfig(n_nodes=V100.n_nodes, history=12, interval=1800.0,
+                    faults=plan)
+    frac = {"fault-free": 0.3, "faulty-before": 0.2, "faulty-across": 0.55,
+            "faulty-after": 0.8, "extension": 0.7, "start": 0.02}[case]
+    lo, hi = ProvisionEnv(jobs, cfg, seed=0)._t_start_range
+    return jobs, cfg, lo + frac * (hi - lo)
+
+
+SCALAR_CASES = ["fault-free", "faulty-before", "faulty-across",
+                "faulty-after", "extension", "cascade-at-t0", "start"]
+
+
+@pytest.mark.parametrize("case", SCALAR_CASES)
+def test_scalar_differential_reset_matches_fork(case, monkeypatch):
+    """``ProvisionEnv.reset`` served off the timeline is bit-identical to
+    the fork path (``differential=False``): history, obs, the
+    predecessor's start and wait, ``sim.now``, and 50 further steps —
+    on a fault-free world, on both sides of the first fault, where the
+    placement runs past the recorded reach (the frontier extends), on a
+    cascade at t0 and on a proved start."""
+    jobs, cfg, t0 = _world(case)
+    if case == "extension":
+        monkeypatch.setattr(provisioner, "TIMELINE_CHUNK", 12 * HOUR)
+    cache = ReplayCheckpointCache(jobs, cfg.n_nodes, faults=cfg.faults)
+    diff = make_env(jobs, cfg, seed=7, cache=cache)
+    fork = make_env(jobs, cfg, seed=7, differential=False,
+                    cache=ReplayCheckpointCache(jobs, cfg.n_nodes,
+                                                faults=cfg.faults))
+    telemetry.reset()
+    with telemetry.capture():
+        a = time.perf_counter()
+        od = diff.reset(t_start=t0)
+        spans = telemetry.totals(a, time.perf_counter())
+    telemetry.reset()
+    of = fork.reset(t_start=t0)
+    np.testing.assert_array_equal(diff.hist.matrix(), fork.hist.matrix())
+    for k in od:
+        np.testing.assert_array_equal(od[k], of[k], err_msg=k)
+    assert (diff.pred.start_time, diff.pred.wait_time) == \
+        (fork.pred.start_time, fork.pred.wait_time)
+    assert diff.sim.now == fork.sim.now
+    # what the reset took: the timeline's placement, or the fork path
+    placed = case != "faulty-after"
+    assert spans["env.place"].count == int(placed)
+    assert spans["env.fork"].count == spans["env.warmup"].count == 1
+    if placed:
+        pl = provisioner.place_predecessor(
+            cache, cache.timeline(until=diff.pred.submit_time), diff.pred,
+            cfg.interval)
+        assert spans["env.replay"].count == int(case == "cascade-at-t0")
+        assert provisioner.served(pl, diff.pred.submit_time) == \
+            (case != "cascade-at-t0")
+        if case == "start":
+            assert pl.kind == "start"
+        if case == "extension":
+            assert cache.misses > 2     # the reach moved more than once
+    for n in range(50):
+        step = [env.step(int(n == 49)) for env in (diff, fork)]
+        (o1, r1, d1, i1), (o2, r2, d2, i2) = step
+        for k in o1:
+            np.testing.assert_array_equal(o1[k], o2[k], err_msg=k)
+        assert (r1, d1, i1) == (r2, d2, i2)
+        if d1:
+            break
+    assert d1
+
+
+# ------------------------------------------ frontier-bounded timeline
+def test_bounded_timeline_answers_as_the_whole_trace(trace_cfg):
+    """Below its reach a frontier-bounded timeline samples and places as
+    the whole-trace timeline of the same replay does, and it certifies
+    nothing at or past ``valid_until``."""
+    jobs, cfg = trace_cfg
+    cache = ReplayCheckpointCache(jobs, cfg.n_nodes)
+    lo, hi = ProvisionEnv(jobs, cfg, seed=0)._t_start_range
+    part = cache.timeline(until=lo + 0.3 * (hi - lo))
+    reach = part.valid_until
+    assert np.isfinite(reach) and part.open_ended
+    whole = cache.timeline()
+    assert whole is not part and whole.valid_until == float("inf")
+    ts = np.linspace(lo - DAY, reach, 97)[:-1]
+    a, b = part.sample_lanes(ts), whole.sample_lanes(ts)
+    for f in a.__dataclass_fields__:
+        np.testing.assert_array_equal(getattr(a, f), getattr(b, f), f)
+    n_open = 0
+    for t0 in np.linspace(lo, reach + 2 * DAY, 60):
+        args = (float(t0), 1, cfg.sub_limit, cfg.sub_limit, 5_000_001,
+                cfg.interval)
+        p, w = part.place(*args), whole.place(*args)
+        if t0 >= reach:
+            assert p.kind == "fallback"
+            continue
+        assert p.t < reach
+        if p.exhausted:
+            n_open += 1
+            assert p.kind == "cascade" and w.t >= p.t
+        else:
+            assert (p.kind, p.t, p.pass_pos, p.pass_size) == \
+                (w.kind, w.t, w.pass_pos, w.pass_size)
+    assert n_open > 0
+
+
+def test_advanced_frontier_records_the_pristine_timeline(trace_cfg):
+    """The frontier records from t=0 whatever forks moved it first: a
+    cache forked ahead before its first ``timeline()`` holds the same
+    background as a pristine drain — job arrays, start log, the passes
+    that start jobs and every sample. (Passes that start nothing may
+    fall at other replay boundaries.)"""
+    jobs, cfg = trace_cfg
+    pristine = ReplayCheckpointCache(jobs, cfg.n_nodes).timeline()
+    cache = ReplayCheckpointCache(jobs, cfg.n_nodes)
+    cache.fork_at(10.3 * DAY + 17.0)
+    cache.fork_at(3.1 * DAY)
+    moved = cache.timeline()
+    for name in ("sub", "rt", "lim", "nn", "ids", "log_idx", "log_t",
+                 "log_end", "first_start", "_rsnap", "_qsnap"):
+        np.testing.assert_array_equal(getattr(pristine, name),
+                                      getattr(moved, name), name)
+    m, k = pristine.rec_nstart > 0, moved.rec_nstart > 0
+    for name in ("rec_t", "rec_kind", "rec_free_entry", "rec_free_exit",
+                 "rec_free_bf", "rec_shadow", "rec_spare", "rec_head",
+                 "rec_nstart"):
+        np.testing.assert_array_equal(getattr(pristine, name)[m],
+                                      getattr(moved, name)[k], name)
+    ts = np.linspace(jobs[0].submit_time, jobs[-1].submit_time, 101)
+    a, b = pristine.sample_lanes(ts), moved.sample_lanes(ts)
+    for f in a.__dataclass_fields__:
+        np.testing.assert_array_equal(getattr(a, f), getattr(b, f), f)
+
+
+def test_reset_inside_the_reach_does_not_rebuild(trace_cfg):
+    """A scalar reset inside the recorded reach reuses the timeline (a
+    hit) and moves no frontier; one past it rebuilds once."""
+    jobs, cfg = trace_cfg
+    cache = ReplayCheckpointCache(jobs, cfg.n_nodes)
+    lo, hi = ProvisionEnv(jobs, cfg, seed=0)._t_start_range
+    make_env(jobs, cfg, seed=1, cache=cache).reset(
+        t_start=lo + 0.5 * (hi - lo))
+    tl, misses = cache._timeline, cache.misses
+    assert np.isfinite(tl.valid_until)
+    hits = cache.hits
+    make_env(jobs, cfg, seed=2, cache=cache).reset(
+        t_start=lo + 0.3 * (hi - lo))
+    assert cache._timeline is tl and cache.misses == misses
+    assert cache.hits > hits
+    make_env(jobs, cfg, seed=3, cache=cache).reset(t_start=tl.valid_until)
+    assert cache._timeline is not tl and cache.misses > misses
