@@ -57,7 +57,6 @@ import numpy as np
 from repro import telemetry
 from repro.sim.faults import FaultPlan
 from repro.sim.trace import Job
-from repro.sim.workload import pair_outcome
 from repro.train.fault import PreemptionGuard
 from .policy import FallbackPolicy, Policy, batch_obs
 from .provisioner import EnvConfig, ProvisionEnv, ReplayCheckpointCache
@@ -349,10 +348,11 @@ class ChainResult:
 class ChainLane:
     """The stepwise core of one journaled ``links``-link chain.
 
-    Reuses ``ProvisionEnv``'s episode machinery (warm-up, history window,
-    observation encoding) but rolls the chain forward instead of ending
-    after one pair: once link ``i``'s successor starts, it becomes the
-    next link's predecessor and the decision loop continues.
+    Drives ``ProvisionEnv`` through its own steps (reset, ``wait``,
+    ``submit_successor`` through the lane's control plane) but rolls the
+    chain forward (``roll``) instead of ending after one pair: once link
+    ``i``'s successor starts, it becomes the next link's predecessor and
+    the decision loop continues.
 
     A lane is a re-entrant state machine so a multiplexing service can
     interleave many of them: ``begin()`` resets the episode, replays the
@@ -391,6 +391,7 @@ class ChainLane:
         self.n_decisions = self.n_replayed = self.n_fallbacks = 0
         self._di = 0
         self._seen: Dict[int, Tuple[float, float]] = {}
+        self._ctrl0 = (0, 0)        # ctrl counters at the link's submit
         # owned fault attribution (fed by the simulator's kill observer)
         self._owned: set = set()
         self._n_faults = 0
@@ -420,46 +421,28 @@ class ChainLane:
             self._n_faults += 1
             self._n_requeues += hit
 
-    def _pred_end(self) -> float:
-        pred = self.env.pred
-        if pred.start_time < 0:      # fault-killed, still queued: unknown end
-            return float("inf")
-        return pred.start_time + min(pred.runtime, pred.time_limit)
+    def _submit_owned(self, job: Job) -> None:
+        """The successor step's submit: through the retried control
+        plane, with the job counted as the chain's own first."""
+        self._owned.add(job.job_id)
+        self._ctrl0 = (self.ctrl.n_retries, self.ctrl.n_errors)
+        self.ctrl.submit(self.env.sim, job)
 
-    def _submit_link(self, link: int, forced: bool) -> Dict:
-        """Submit link ``link``'s sub-job through the retried control
-        plane, run it to start, score it against its predecessor, and
-        roll the chain forward (successor becomes the next predecessor)."""
-        env = self.env
-        started = env.pred.start_time >= 0
-        pred_end = self._pred_end()
-        t_sub = (max(env.sim.now, pred_end) if forced and started
-                 else env.sim.now)
-        env.sim.run_until(t_sub)
-        succ = env.chain.make_sub(link, t_sub)
-        self._owned.add(succ.job_id)
-        retries0, errors0 = self.ctrl.n_retries, self.ctrl.n_errors
-        self.ctrl.submit(env.sim, succ)
-        wait = env.sim.run_until_started(succ)
-        pred = env.pred
-        if pred.end_time < 0:
-            if pred.start_time >= 0:
-                pred.end_time = pred.start_time + min(pred.runtime,
-                                                      pred.time_limit)
-            else:
-                pred.end_time = t_sub      # killed, never restarted
-        kind, amount = pair_outcome(pred, succ)
-        r = shape_reward(kind, amount, env.cfg.reward)
-        info = {"link": link, "kind": kind, "amount_s": amount,
-                "wait_s": wait, "forced": forced, "reward": r,
-                "pred_id": pred.job_id, "succ_id": succ.job_id,
-                "n_retries": self.ctrl.n_retries - retries0,
-                "n_ctrl_errors": self.ctrl.n_errors - errors0}
-        # the chain rolls forward: the successor is the next predecessor
-        env.pred = succ
-        env.succ = None
-        env._fc0 = (env.sim.n_node_failures, env.sim.n_requeues)
-        return info
+    def _close_link(self, pred: Job, succ: Job, kind: str, amount: float,
+                    wait: float, forced: bool, **owned: int) -> None:
+        """Record link ``self.link``'s outcome and count the link off. The
+        solo and co-sim lanes' one record; ``owned`` appends the co-sim
+        lane's own fault and requeue counts."""
+        self.outcomes.append({
+            "link": self.link, "kind": kind, "amount_s": amount,
+            "wait_s": wait, "forced": forced,
+            "reward": shape_reward(kind, amount, self.env.cfg.reward),
+            "pred_id": pred.job_id, "succ_id": succ.job_id,
+            "n_retries": self.ctrl.n_retries - self._ctrl0[0],
+            "n_ctrl_errors": self.ctrl.n_errors - self._ctrl0[1], **owned})
+        self._seen[pred.job_id] = (pred.start_time, pred.end_time)
+        self.link += 1
+        self.done = self.link > self.links
 
     # ----------------------------------------------------------- stepping
     def begin(self, t_start: Optional[float] = None) -> None:
@@ -511,18 +494,17 @@ class ChainLane:
         self._di += 1
         self.n_decisions += 1
         self.n_fallbacks += int(fell_back)
-        forced = (action == 0
-                  and env.sim.now + env.cfg.interval >= self._pred_end())
+        forced = env.forced(action)
         if action == 1 or forced:
-            pred = env.pred
-            info = self._submit_link(self.link, forced)
-            self._seen[pred.job_id] = (pred.start_time, pred.end_time)
-            self.outcomes.append(info)
-            self.link += 1
-            if self.link > self.links:
-                self.done = True
+            # the env's successor step, then the chain rolls forward: the
+            # successor is the next link's predecessor
+            _, out = env.submit_successor(forced, self.link,
+                                          self._submit_owned)
+            self._close_link(env.pred, env.succ, out["kind"],
+                             out["amount_s"], out["wait_s"], forced)
+            env.roll(env.succ)
         else:
-            env._advance(env.cfg.interval)
+            env.wait()
         with telemetry.span("env.encode"):
             self.obs = env.obs()
 
@@ -532,9 +514,10 @@ class ChainLane:
         tail = self.env.pred
         seen = dict(self._seen)
         if tail is not None and tail.job_id not in seen:
-            end = (tail.start_time + min(tail.runtime, tail.time_limit)
-                   if tail.start_time >= 0 else -1.0)
-            seen[tail.job_id] = (tail.start_time, end)
+            # a tail link that is not running has no end yet: -1.0
+            seen[tail.job_id] = (tail.start_time,
+                                 self.env.pred_end()
+                                 if tail.start_time >= 0 else -1.0)
         return ChainResult(
             reason=reason, outcomes=list(self.outcomes),
             schedule=sorted((jid, st, en) for jid, (st, en) in seen.items()),

@@ -28,7 +28,8 @@ Contract (pinned by ``tests/test_multitenant.py``): with ``tenants=1``
 this env is bit-identical to ``make_vector_env``'s single-tenant engine
 — observations, rewards, dones and infos — because the one-tenant round
 protocol reduces operation-for-operation to the scalar
-``_submit_successor`` sequence. Construct through
+``submit_successor`` sequence (both apply the chain-link rules of
+``repro.sim.workload``). Construct through
 ``repro.sim.make_co_vector_env`` (the factory owns cache wiring), not
 directly.
 """
@@ -41,6 +42,7 @@ import numpy as np
 from repro.sim.multitenant import (FLEET_DIM, MultiTenantSim,
                                    make_tenant_chain, sample_tenant_batch)
 from repro.sim.trace import Job
+from repro.sim.workload import forced_submit, link_end
 from .provisioner import DAY, EnvConfig, ReplayCheckpointCache
 from .reward import shape_reward
 from .state import (STATE_DIM, StateHistoryBatch, encode_sample_batch,
@@ -165,10 +167,8 @@ class CoTenantVectorEnv:
             np.fromiter(
                 (self.worlds[int(i) // T].preds[int(i) % T].wait_time
                  for i in rows), np.float64, rows.size).clip(min=0.0), 0.0)
-        self._pred_end[rows] = np.where(
-            starts >= 0,
-            starts + np.minimum(self._pred_rt[rows], self._pred_limit[rows]),
-            np.inf)
+        self._pred_end[rows] = link_end(starts, self._pred_rt[rows],
+                                        self._pred_limit[rows])
 
     # ------------------------------------------------------------ episode
     def warmup_point(self, t0: float) -> float:
@@ -232,8 +232,8 @@ class CoTenantVectorEnv:
             self._pred_rt[r] = pred.runtime
             self._pred_qtime[r] = max(pred.wait_time, 0.0)
             self._pred_start[r] = pred.start_time
-        self._pred_end[:] = self._pred_start + np.minimum(
-            self._pred_rt, self._pred_limit)
+        self._pred_end[:] = link_end(self._pred_start, self._pred_rt,
+                                     self._pred_limit)
         self._has_pred[:] = True
         self._hist.push(self._encode_rows(rows), rows)
         self.dones = np.zeros(self.batch, bool)
@@ -289,8 +289,8 @@ class CoTenantVectorEnv:
             for t in np.flatnonzero(~world.done & ~world.pending):
                 t = int(t)
                 a = int(actions[base + t])
-                forced = (a == 0 and round_now + self.cfg.interval
-                          >= self._pred_end[base + t])
+                forced = forced_submit(a, round_now, self.cfg.interval,
+                                       self._pred_end[base + t])
                 if a == 1 or forced:
                     world.request_submit(t, forced)
             world.flush_submits()

@@ -41,7 +41,7 @@ import bisect
 import contextlib
 import copy
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -50,7 +50,9 @@ from repro.sim.faults import FaultPlan
 from repro.sim.simulator import SlurmSimulator, sample_batch, step_batch
 from repro.sim.timeline import BackgroundTimeline, Placement
 from repro.sim.trace import Job
-from repro.sim.workload import SubJobChain, pair_outcome
+from repro.sim.workload import (SubJobChain, forced_submit, link_end,
+                                pair_outcome, settle_pred_end,
+                                submit_instant)
 from .reward import RewardConfig, shape_reward
 from .state import (SAMPLE_INTERVAL, STATE_DIM, StateHistory,
                     StateHistoryBatch, encode_sample_batch, encode_snapshot,
@@ -76,10 +78,6 @@ class EnvConfig:
     # deterministic fault schedule threaded into every simulator the env
     # (or its checkpoint cache) builds; None == fault-free
     faults: Optional[FaultPlan] = None
-    # serve resets (scalar and vector) from the differential engine (the
-    # immutable background timeline) where provably exact, falling back
-    # to real forks otherwise; False forces the classic fork path
-    differential: bool = True
 
 
 _QUIET = contextlib.nullcontext()
@@ -174,8 +172,9 @@ class ProvisionEnv:
         lo, hi = self._t_start_range
         t0 = t_start if t_start is not None else float(self.rng.uniform(lo, hi))
         with telemetry.span("env.reset"):
-            if (self.cache is not None and self.cfg.differential
-                    and np.isfinite(t0)):
+            # served off the immutable background timeline where it is
+            # valid, on a real fork otherwise
+            if self.cache is not None and np.isfinite(t0):
                 tl = self.cache.timeline(until=t0)
                 if t0 < tl.valid_until:
                     return self._begin_differential(tl, t0)
@@ -248,49 +247,47 @@ class ProvisionEnv:
         self.hist.push(self._snapshot())
         return self.obs()
 
+    def pred_end(self) -> float:
+        """The predecessor's projected end (inf while it is not running)."""
+        p = self.pred
+        return link_end(p.start_time, p.runtime, p.time_limit)
+
+    def forced(self, action: int) -> bool:
+        """Whether ``action`` is a wait that the predecessor's end turns
+        into a (reactive) submit."""
+        return bool(forced_submit(action, self.sim.now, self.cfg.interval,
+                                  self.pred_end()))
+
+    def wait(self) -> None:
+        """Let one decision interval pass: an ``env.advance`` span for the
+        simulator's step and an ``env.encode`` span for the history push."""
+        self._advance(self.cfg.interval)
+
     def step(self, action: int) -> Tuple[Dict, float, bool, Dict]:
         """action: 1=submit successor, 0=wait. Returns (obs, reward, done, info)."""
         assert self.pred is not None and self.succ is None
-        # a fault-killed (requeued, not yet restarted) predecessor has no
-        # known end: it cannot force a reactive submission until restarted
-        pred_end = (self.pred.start_time + min(self.pred.runtime,
-                                               self.pred.time_limit)
-                    if self.pred.start_time >= 0 else float("inf"))
-        forced = False
-        if action == 0:
-            if self.sim.now + self.cfg.interval >= pred_end:
-                forced = True        # limit expired -> reactive fallback
-            else:
-                self._advance(self.cfg.interval)
-                return self.obs(), 0.0, False, {}
-        r, info = self._submit_successor(forced)
+        forced = self.forced(action)
+        if action == 0 and not forced:
+            self.wait()
+            return self.obs(), 0.0, False, {}
+        r, info = self.submit_successor(forced)
         return self.obs(), r, True, info
 
-    def _submit_successor(self, forced: bool) -> Tuple[float, Dict]:
-        """Submit the successor (possibly forced at the predecessor's end),
-        run it to start, and score the episode outcome. Shared by the
-        scalar step and the vector env's batched step (which serves the
-        final observation from its own ring instead of ``obs()``)."""
-        started = self.pred.start_time >= 0
-        pred_end = (self.pred.start_time + min(self.pred.runtime,
-                                               self.pred.time_limit)
-                    if started else float("inf"))
-        t_sub = max(self.sim.now, pred_end if forced and started
-                    else self.sim.now)
+    def submit_successor(self, forced: bool, link: int = 1,
+                         submit: Optional[Callable[[Job], None]] = None
+                         ) -> Tuple[float, Dict]:
+        """Submit link ``link``'s sub-job (at the predecessor's end where
+        ``forced``) through ``submit`` (the simulator's own by default),
+        run it to start, and score it against the predecessor. Shared by
+        the scalar step, the vector env's batched step (which serves the
+        final observation from its own ring) and the serving lanes (which
+        submit through their control plane, then ``roll`` the chain)."""
+        t_sub = submit_instant(self.sim.now, self.pred_end(), forced)
         self.sim.run_until(t_sub)
-        self.succ = self.chain.make_sub(1, t_sub)
-        self.sim.submit(self.succ)
+        self.succ = self.chain.make_sub(link, t_sub)
+        (submit or self.sim.submit)(self.succ)
         wait = self.sim.run_until_started(self.succ)
-        if self.pred.end_time < 0:
-            if self.pred.start_time >= 0:
-                # the predecessor (original or fault-requeued restart)
-                # runs to its limit from its current start
-                self.pred.end_time = self.pred.start_time + min(
-                    self.pred.runtime, self.pred.time_limit)
-            else:
-                # killed and still queued when the successor went in: the
-                # service has been down since before the submission
-                self.pred.end_time = t_sub
+        settle_pred_end(self.pred, t_sub)
         kind, amount = pair_outcome(self.pred, self.succ)
         r = shape_reward(kind, amount, self.cfg.reward)
         f0, rq0 = self._fc0
@@ -298,6 +295,13 @@ class ProvisionEnv:
                    "forced": forced,
                    "n_faults": self.sim.n_node_failures - f0,
                    "n_requeues": self.sim.n_requeues - rq0}
+
+    def roll(self, succ: Job) -> None:
+        """The chain rolls forward: the started successor ``succ`` becomes
+        the next link's predecessor, and the fault and requeue window
+        reopens."""
+        self.pred, self.succ = succ, None
+        self._fc0 = (self.sim.n_node_failures, self.sim.n_requeues)
 
 
 class ReplayCheckpointCache:
@@ -619,11 +623,8 @@ class VectorProvisionEnv:
             starts >= 0,
             np.fromiter((self.envs[int(i)].pred.wait_time for i in lanes),
                         np.float64, lanes.size).clip(min=0.0), 0.0)
-        self._pred_end[lanes] = np.where(
-            starts >= 0,
-            starts + np.minimum(self._pred_rt[lanes],
-                                self._pred_limit[lanes]),
-            np.inf)
+        self._pred_end[lanes] = link_end(starts, self._pred_rt[lanes],
+                                         self._pred_limit[lanes])
 
     @property
     def _t_start_range(self) -> Tuple[float, float]:
@@ -660,9 +661,8 @@ class VectorProvisionEnv:
         # materializes one); lanes whose episode reaches the first fault
         # event — where the timeline stops being the truth — fall back to
         # the classic fork-per-lane path
-        tl = self.cache.timeline() if self.cfg.differential else None
-        diff = (np.isfinite(t0s) & (t0s < tl.valid_until)
-                if tl is not None else np.zeros(self.batch, bool))
+        tl = self.cache.timeline()
+        diff = np.isfinite(t0s) & (t0s < tl.valid_until)
         fb = np.flatnonzero(~diff)
         # checkpointed forks, ascending so the frontier advances monotonically
         for i in fb[np.argsort(wps[fb], kind="stable")]:
@@ -731,8 +731,8 @@ class VectorProvisionEnv:
             (e.pred.wait_time for e in self.envs), np.float64, self.batch),
             0.0)
         self._pred_start[:] = starts
-        self._pred_end[:] = starts + np.minimum(self._pred_rt,
-                                                self._pred_limit)
+        self._pred_end[:] = link_end(starts, self._pred_rt,
+                                     self._pred_limit)
         span = np.maximum(starts - t0_eff, 0.0)
         st["total_intervals"] += int(pushes.sum()) + int(
             (span // max(self.cfg.interval, 1.0)).sum()) + self.batch
@@ -763,8 +763,8 @@ class VectorProvisionEnv:
             self._sync_pred_state(live)
         nows = np.fromiter((self.envs[int(i)].sim.now for i in live),
                            np.float64, live.size)
-        forced = (actions[live] == 0) & (
-            nows + self.cfg.interval >= self._pred_end[live])
+        forced = forced_submit(actions[live], nows, self.cfg.interval,
+                               self._pred_end[live])
         submit = (actions[live] == 1) | forced
         sub_idx = live[submit]
         wait_idx = live[~submit]
@@ -772,7 +772,7 @@ class VectorProvisionEnv:
         # per-lane cursor (the scalar env pushes nothing on submission)
         for i, f in zip(sub_idx, forced[submit]):
             i = int(i)
-            r, info = self.envs[i]._submit_successor(bool(f))
+            r, info = self.envs[i].submit_successor(bool(f))
             rewards[i] = r
             infos[i] = info
             self.dones[i] = True

@@ -46,7 +46,6 @@ from repro.core.control import (JOURNAL_VERSION, ChainLane, ChainResult,
                                 DecisionJournal, JournalCorruptionError,
                                 RetryPolicy)
 from repro.core.provisioner import EnvConfig, ReplayCheckpointCache
-from repro.core.reward import shape_reward
 from repro.core.state import StateHistory, encode_cluster, encode_pair
 from repro.sim.multitenant import (MultiTenantSim, TenantOutcome,
                                    make_tenant_chain)
@@ -76,7 +75,6 @@ class CoSimChainLane(ChainLane):
         self.cosim = cosim
         self.tenant = tenant
         self.round_applied = -1      # last world round this lane decided
-        self._ctrl0 = (0, 0)         # ctrl counters at the live submit
         cosim._register(self)
 
     # ------------------------------------------------------------ journal
@@ -142,9 +140,7 @@ class CoSimChainLane(ChainLane):
         self._di += 1
         self.n_decisions += 1
         self.n_fallbacks += int(fell_back)
-        env = self.env
-        forced = (action == 0
-                  and env.sim.now + env.cfg.interval >= self._pred_end())
+        forced = self.env.forced(action)
         if action == 1 or forced:
             # deferred: the world flushes all of this round's submissions
             # in canonical order when the round advances
@@ -152,27 +148,15 @@ class CoSimChainLane(ChainLane):
         self.round_applied = self.cosim.round
 
     def _finish_link(self, out: TenantOutcome) -> None:
-        """The shared clock crossed this lane's successor start: score the
-        link (same info shape as the solo ``_submit_link``) and roll the
-        chain forward."""
-        env = self.env
-        r = shape_reward(out.kind, out.amount_s, env.cfg.reward)
-        info = {"link": self.link, "kind": out.kind,
-                "amount_s": out.amount_s, "wait_s": out.wait_s,
-                "forced": out.forced, "reward": r,
-                "pred_id": out.pred.job_id, "succ_id": out.succ.job_id,
-                "n_retries": self.ctrl.n_retries - self._ctrl0[0],
-                "n_ctrl_errors": self.ctrl.n_errors - self._ctrl0[1],
-                "n_faults": out.n_faults, "n_requeues": out.n_requeues}
-        self._seen[out.pred.job_id] = (out.pred.start_time,
-                                       out.pred.end_time)
-        self.outcomes.append(info)
-        env.pred = out.succ
-        env.succ = None
+        """The shared clock crossed this lane's successor start: record
+        the link (the solo lane's record plus the owned fault counts) and
+        roll the chain forward."""
+        self._close_link(out.pred, out.succ, out.kind, out.amount_s,
+                         out.wait_s, out.forced, n_faults=out.n_faults,
+                         n_requeues=out.n_requeues)
+        self.env.roll(out.succ)
         self.cosim.world.roll(self.tenant)
-        self.link += 1
-        if self.link > self.links:
-            self.done = True
+        if self.done:
             self.cosim.world.finish(self.tenant)
 
     def result(self, reason: str) -> ChainResult:

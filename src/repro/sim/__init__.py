@@ -11,4 +11,4 @@ from .multitenant import (MultiTenantSim, TenantOutcome,  # noqa: F401
                           make_tenant_chain, sample_tenant_batch)
 from .trace import (PROFILES, ClusterProfile, Job, clean_trace,  # noqa: F401
                     split_trace, synthesize_trace, trace_stats)
-from .workload import SubJobChain, pair_outcome, run_pair  # noqa: F401
+from .workload import SubJobChain, pair_outcome  # noqa: F401

@@ -31,11 +31,14 @@ batched env, the co-sim ``ProvisionService`` mode for serving):
 3. the caller advances the shared clock one lockstep interval — or,
    when every live tenant is pending, ``fast_forward`` runs each
    pending successor to its start;
-4. ``resolve_ready`` scores tenants whose successor started, with the
-   exact float expressions of the single-tenant episode engine — with
-   one tenant, the request/flush/fast-forward/resolve sequence reduces
-   operation-for-operation to ``ProvisionEnv._submit_successor``, which
-   is what pins the N=1 co-sim bit-identity contract.
+4. ``resolve_ready`` scores tenants whose successor started.
+
+Steps 1 and 4 apply the chain-link rules of ``repro.sim.workload``
+(``link_end``, ``submit_instant``, ``settle_pred_end``), the ones the
+single-tenant engine applies — with one tenant, the
+request/flush/fast-forward/resolve sequence reduces
+operation-for-operation to ``ProvisionEnv.submit_successor``, which is
+what pins the N=1 co-sim bit-identity contract.
 
 Determinism: given the per-tenant decision sequences, the shared
 schedule is a pure function of (trace, fault plan, tenant chains) —
@@ -51,7 +54,8 @@ import numpy as np
 
 from .simulator import SampleBatch, SlurmSimulator, sample_batch
 from .trace import Job
-from .workload import SubJobChain, pair_outcome
+from .workload import (SubJobChain, link_end, pair_outcome,
+                       settle_pred_end, submit_instant)
 
 #: tenant ``t`` draws chain job ids in [t*STRIDE + 10**6, t*STRIDE + 10**7):
 #: disjoint across tenants, far above background trace ids, and tenant 0's
@@ -171,20 +175,14 @@ class MultiTenantSim:
         """The predecessor's projected end (inf while fault-killed and
         still queued — it cannot force a reactive submission)."""
         pred = self.preds[tenant]
-        if pred.start_time < 0:
-            return float("inf")
-        return pred.start_time + min(pred.runtime, pred.time_limit)
+        return link_end(pred.start_time, pred.runtime, pred.time_limit)
 
     def request_submit(self, tenant: int, forced: bool) -> None:
         """Queue tenant ``tenant``'s successor submission for this round.
-        The submit instant is the single-tenant expression evaluated at
-        the round head: now for a voluntary submit, the predecessor's end
-        for a forced (reactive-fallback) one."""
-        pred = self.preds[tenant]
-        started = pred.start_time >= 0
-        pe = self.pred_end(tenant)
-        t_sub = max(self.sim.now, pe if forced and started
-                    else self.sim.now)
+        The submit instant is the single-tenant rule evaluated at the
+        round head: now for a voluntary submit, the predecessor's end for
+        a forced (reactive-fallback) one."""
+        t_sub = submit_instant(self.sim.now, self.pred_end(tenant), forced)
         self.forced[tenant] = forced
         self._req.append((t_sub, tenant))
 
@@ -224,10 +222,10 @@ class MultiTenantSim:
                 self.sim.run_until_started(self.succs[t])
 
     def resolve_ready(self) -> List[TenantOutcome]:
-        """Score every pending tenant whose successor has started, with
-        the single-tenant engine's float expressions: backfill the
-        predecessor's end, classify the pair, attribute the wait and the
-        owned fault/requeue counters to this tenant."""
+        """Score every pending tenant whose successor has started, by the
+        single-tenant engine's rules: settle the predecessor's end,
+        classify the pair, attribute the wait and the owned fault/requeue
+        counters to this tenant."""
         out: List[TenantOutcome] = []
         for t in range(self.tenants):
             if not self.pending[t]:
@@ -236,15 +234,7 @@ class MultiTenantSim:
             if succ.start_time < 0:
                 continue
             pred = self.preds[t]
-            if pred.end_time < 0:
-                if pred.start_time >= 0:
-                    # the predecessor (original or fault-requeued restart)
-                    # runs to its limit from its current start
-                    pred.end_time = pred.start_time + min(pred.runtime,
-                                                          pred.time_limit)
-                else:
-                    # killed and still queued when the successor went in
-                    pred.end_time = succ.submit_time
+            settle_pred_end(pred, succ.submit_time)
             kind, amount = pair_outcome(pred, succ)
             wait = float(succ.start_time - succ.submit_time)
             nf, nr = self.counters(t)

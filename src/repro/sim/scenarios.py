@@ -180,10 +180,9 @@ def make_vector_env(trace: List[Job], cfg, batch: int, *, seed: int = 0,
     ``i`` is bit-identical to ``make_env(trace, cfg, seed=seed + i)``.
     Pass ``cache=`` to share one ``ReplayCheckpointCache`` (and its
     immutable ``BackgroundTimeline``) across envs over the same trace;
-    without it the env builds and owns one. ``differential=False`` in
-    ``overrides`` forces the classic fork-per-lane reset path. For a
-    different batch size over the same wiring use
-    ``VectorProvisionEnv.resized(n)`` on the result."""
+    without it the env builds and owns one. For a different batch size
+    over the same wiring use ``VectorProvisionEnv.resized(n)`` on the
+    result."""
     from repro.core import VectorProvisionEnv
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)

@@ -5,16 +5,27 @@ wall-clock-limited sub-jobs J1..Jk. The provisioner controls WHEN each
 successor is submitted; the outcome per consecutive pair is either an
 INTERRUPTION (successor starts after the predecessor ends) or an OVERLAP
 (successor starts while the predecessor still runs).
+
+The chain-link protocol's rules live here, once, for every engine that
+runs it (the scalar and vector envs, the co-tenant env, the serving
+lanes and ``MultiTenantSim``): a link's projected end (``link_end``),
+the reactive fallback that turns a wait into a submit (``forced_submit``),
+the successor's submit instant (``submit_instant``) and the predecessor's
+end once its successor has gone in (``settle_pred_end``). The N=1 co-sim
+and vector-equals-scalar identities rest on every engine evaluating the
+same float expressions, so change them here or nowhere.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import Tuple
+
+import numpy as np
 
 from .trace import Job
-from .simulator import SlurmSimulator
 
 HOUR = 3600.0
+INF = float("inf")
 
 
 @dataclasses.dataclass
@@ -43,22 +54,39 @@ def pair_outcome(pred: Job, succ: Job) -> Tuple[str, float]:
     return "overlap", -gap
 
 
-def run_pair(sim: SlurmSimulator, chain: SubJobChain, t_pred_submit: float,
-             succ_delay: float) -> Tuple[str, float, Job, Job]:
-    """Reference harness: submit the predecessor at t_pred_submit, the
-    successor ``succ_delay`` seconds after the predecessor STARTS, then run
-    until the outcome is observable. Used by heuristics/offline sampling."""
-    pred = chain.make_sub(0, t_pred_submit)
-    sim.run_until(t_pred_submit)
-    sim.submit(pred)
-    sim.run_until_started(pred)
-    t_succ = pred.start_time + min(succ_delay, chain.sub_limit)
-    succ = chain.make_sub(1, t_succ)
-    sim.run_until(t_succ)
-    sim.submit(succ)
-    sim.run_until_started(succ)
-    # ensure the predecessor end time is known (it runs to its limit)
+def link_end(start, runtime, limit):
+    """A link's projected end: its start plus the smaller of its runtime
+    and limit, or inf while it is not running (not yet started, or
+    fault-killed and queued again — it has no known end). Floats, or
+    numpy arrays over a lane axis."""
+    if isinstance(start, np.ndarray):
+        return np.where(start >= 0, start + np.minimum(runtime, limit),
+                        np.inf)
+    return start + min(runtime, limit) if start >= 0 else INF
+
+
+def forced_submit(action, now, interval, pred_end):
+    """Whether a decision is a wait (``action == 0``) whose next interval
+    reaches the predecessor's end ``pred_end``: the reactive fallback
+    submits the successor instead. A predecessor with no known end (inf)
+    never forces. Scalars, or numpy arrays over a lane axis."""
+    return (action == 0) & (now + interval >= pred_end)
+
+
+def submit_instant(now: float, pred_end: float, forced: bool) -> float:
+    """The successor's submit instant: ``now`` for a voluntary submit,
+    the predecessor's end (never before ``now``) for a forced one; a
+    predecessor with no known end (inf) lets it go in ``now``."""
+    return max(now, pred_end) if forced and pred_end < INF else now
+
+
+def settle_pred_end(pred: Job, t_sub: float) -> None:
+    """Fill in the predecessor's end once its successor, submitted at
+    ``t_sub``, has started: a running predecessor (the original or a
+    fault-requeued restart) runs to its limit from its current start; one
+    killed and still queued has been down since the submission. An end
+    the simulator already recorded stands."""
     if pred.end_time < 0:
-        pred.end_time = pred.start_time + min(pred.runtime, pred.time_limit)
-    kind, amount = pair_outcome(pred, succ)
-    return kind, amount, pred, succ
+        pred.end_time = (link_end(pred.start_time, pred.runtime,
+                                  pred.time_limit)
+                         if pred.start_time >= 0 else t_sub)

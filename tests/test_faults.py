@@ -22,6 +22,8 @@ from repro.sim import (FAULT_PROFILES, FaultPlan, SlurmSimulator,
 from repro.sim.faults import FAIL, REPAIR
 from repro.sim.trace import V100, Job
 
+from _fork_path import ForkPathCache
+
 HOUR = 3600.0
 DAY = 24 * HOUR
 HISTORY = 12
@@ -279,9 +281,9 @@ def test_vector_matches_scalar_under_faults(faulty_world):
                 vr[i] = r[i]
                 vinfos[i] = inf[i]
     # the reference is the scalar fork path (no timeline)
-    fork_cfg = dataclasses.replace(cfg, differential=False)
+    fork_cache = ForkPathCache(jobs, cfg.n_nodes, faults=plan)
     for i in range(B):
-        env = ProvisionEnv(jobs, fork_cfg, seed=50 + i, cache=cache)
+        env = ProvisionEnv(jobs, cfg, seed=50 + i, cache=fork_cache)
         sobs = env.reset(t_start=float(t0s[i]))
         step = 0
         np.testing.assert_array_equal(vec[step]["matrix"][i],

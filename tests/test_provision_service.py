@@ -21,6 +21,8 @@ from repro.sim.faults import FAIL, REPAIR
 from repro.sim.trace import V100
 from repro.train.fault import PreemptionGuard
 
+from _fork_path import ForkPathCache
+
 HOUR = 3600.0
 DAY = 24 * HOUR
 SEED = 11
@@ -687,24 +689,22 @@ def test_solo_service_starts_off_the_timeline_serve_the_same(tmp_path):
     """A fault-free solo service whose tenant starts are served off the
     background timeline answers the same actions, writes the same
     journal bytes and ends with the same schedules as one whose starts
-    take the fork path (``differential=False``)."""
+    take the fork path (a ``ForkPathCache``)."""
     from repro import telemetry
     jobs = synthesize_trace(V100, months=1, seed=5, load_scale=1.0)
     cfg = EnvConfig(n_nodes=V100.n_nodes, history=12, interval=1800.0,
                     sub_limit=8 * HOUR)
 
-    def serve(name, differential):
-        c = dataclasses.replace(cfg, differential=differential)
+    def serve(name, cache):
         policy = _Hashing()
         jdir = tmp_path / name
         telemetry.reset()
         with telemetry.capture():
             t0 = time.perf_counter()
             res = ProvisionService(
-                jobs, c, policy,
+                jobs, cfg, policy,
                 svc=ServiceConfig(tenants=TENANTS, links=LINKS, max_batch=4),
-                seed=SEED, journal_dir=str(jdir),
-                cache=ReplayCheckpointCache(jobs, c.n_nodes),
+                seed=SEED, journal_dir=str(jdir), cache=cache,
                 retry_factory=_retry_factory).run()
             tot = telemetry.totals(t0, time.perf_counter())
         telemetry.reset()
@@ -713,8 +713,10 @@ def test_solo_service_starts_off_the_timeline_serve_the_same(tmp_path):
         journals = {p.name: p.read_bytes() for p in sorted(jdir.iterdir())}
         return res, policy.actions, journals, tot["env.place"].count
 
-    new, new_actions, new_journals, placed = serve("timeline", True)
-    ref, ref_actions, ref_journals, forked = serve("fork", False)
+    new, new_actions, new_journals, placed = serve(
+        "timeline", ReplayCheckpointCache(jobs, cfg.n_nodes))
+    ref, ref_actions, ref_journals, forked = serve(
+        "fork", ForkPathCache(jobs, cfg.n_nodes))
     assert (placed, forked) == (TENANTS, 0)
     assert new_actions == ref_actions
     assert any(1 in batch for batch in new_actions)

@@ -21,6 +21,8 @@ from repro.sim import (FaultPlan, SlurmSimulator, get_fault_spec, make_env,
 from repro.sim.faults import FAIL, REPAIR
 from repro.sim.trace import V100, Job
 
+from _fork_path import ForkPathCache
+
 HOUR = 3600.0
 DAY = 24 * HOUR
 
@@ -76,7 +78,8 @@ def test_differential_engine_bit_identical_fault_free(trace_cfg):
     policy = (lambda t: 1 if t >= 3 else 0)
 
     venv_d = make_vector_env(jobs, cfg, B, seed=0)
-    venv_f = make_vector_env(jobs, cfg, B, seed=0, differential=False)
+    venv_f = make_vector_env(jobs, cfg, B, seed=0,
+                             cache=ForkPathCache(jobs, cfg.n_nodes))
     a = run_episode(venv_d, ts, policy)
     b = run_episode(venv_f, ts, policy)
     # the engine served every lane (fault-free: timeline covers the trace)
@@ -98,7 +101,8 @@ def test_differential_covers_both_placement_kinds(trace_cfg):
     lo, hi = ProvisionEnv(jobs, cfg, seed=0)._t_start_range
     ts = [lo + (i + 0.5) / B * (hi - lo) for i in range(B)]
     venv_d = make_vector_env(jobs, cfg, B, seed=0)
-    venv_f = make_vector_env(jobs, cfg, B, seed=0, differential=False)
+    venv_f = make_vector_env(jobs, cfg, B, seed=0,
+                             cache=ForkPathCache(jobs, cfg.n_nodes))
     venv_d.reset(t_starts=ts)
     venv_f.reset(t_starts=ts)
     st_ = venv_d.reset_stats
@@ -124,7 +128,9 @@ def test_differential_faulted_lanes_fall_back(trace_cfg):
     ts = [lo + 0.1 * (hi - lo), lo + 0.8 * (hi - lo)]
     policy = (lambda t: 1 if t >= 3 else 0)
     venv_d = make_vector_env(jobs, cfg, 2, seed=0)
-    venv_f = make_vector_env(jobs, cfg, 2, seed=0, differential=False)
+    venv_f = make_vector_env(jobs, cfg, 2, seed=0,
+                             cache=ForkPathCache(jobs, cfg.n_nodes,
+                                                 faults=plan))
     a = run_episode(venv_d, ts, policy)
     b = run_episode(venv_f, ts, policy)
     assert venv_d.reset_stats["diff_lanes"] == 1       # pre-fault lane
@@ -151,7 +157,8 @@ def test_differential_matches_fork_under_faults_property(seed, fracs):
     ts = [lo + f * (hi - lo) for f in fracs]
     venv_d = make_vector_env(jobs, cfg, len(ts), seed=seed % 97)
     venv_f = make_vector_env(jobs, cfg, len(ts), seed=seed % 97,
-                             differential=False)
+                             cache=ForkPathCache(jobs, cfg.n_nodes,
+                                                 faults=plan))
     venv_d.reset(t_starts=ts)
     venv_f.reset(t_starts=ts)
     assert _pred_times(venv_d) == _pred_times(venv_f)
@@ -183,9 +190,9 @@ def test_schedule_view_read_only(trace_cfg):
 
 def test_factory_overrides_do_not_mutate_cfg(trace_cfg):
     jobs, cfg = trace_cfg
-    venv = make_vector_env(jobs, cfg, 1, seed=0, differential=False)
-    assert venv.cfg.differential is False
-    assert cfg.differential is True            # replace(), not mutation
+    venv = make_vector_env(jobs, cfg, 1, seed=0, interval=900.0)
+    assert venv.cfg.interval == 900.0
+    assert cfg.interval == 1800.0              # replace(), not mutation
     env = make_env(jobs, cfg, seed=3, history=7)
     assert env.cfg.history == 7 and cfg.history != 7
     assert isinstance(env, ProvisionEnv)
@@ -260,7 +267,7 @@ SCALAR_CASES = ["fault-free", "faulty-before", "faulty-across",
 @pytest.mark.parametrize("case", SCALAR_CASES)
 def test_scalar_differential_reset_matches_fork(case, monkeypatch):
     """``ProvisionEnv.reset`` served off the timeline is bit-identical to
-    the fork path (``differential=False``): history, obs, the
+    the fork path (a ``ForkPathCache``): history, obs, the
     predecessor's start and wait, ``sim.now``, and 50 further steps —
     on a fault-free world, on both sides of the first fault, where the
     placement runs past the recorded reach (the frontier extends), on a
@@ -270,9 +277,9 @@ def test_scalar_differential_reset_matches_fork(case, monkeypatch):
         monkeypatch.setattr(provisioner, "TIMELINE_CHUNK", 12 * HOUR)
     cache = ReplayCheckpointCache(jobs, cfg.n_nodes, faults=cfg.faults)
     diff = make_env(jobs, cfg, seed=7, cache=cache)
-    fork = make_env(jobs, cfg, seed=7, differential=False,
-                    cache=ReplayCheckpointCache(jobs, cfg.n_nodes,
-                                                faults=cfg.faults))
+    fork = make_env(jobs, cfg, seed=7,
+                    cache=ForkPathCache(jobs, cfg.n_nodes,
+                                        faults=cfg.faults))
     telemetry.reset()
     with telemetry.capture():
         a = time.perf_counter()
